@@ -31,7 +31,8 @@ def _grad(objective, margins, y):
 
 
 class _Node:
-    __slots__ = ("feature", "thr", "left", "right", "value", "default_left")
+    __slots__ = ("feature", "thr", "left", "right", "value", "default_left",
+                 "gain")
 
     def __init__(self):
         self.feature = -1
@@ -39,6 +40,7 @@ class _Node:
         self.left = self.right = None
         self.value = 0.0
         self.default_left = False
+        self.gain = 0.0
 
 
 def _best_split_hist(x, g, h, idx, max_bins, cuts, bins, lam, mcw):
@@ -108,7 +110,7 @@ def _grow(x, g, h, idx, depth, max_depth, lam, mcw, splitter):
     left = (v <= thr) & ~miss
     if dl:
         left |= miss
-    node.feature, node.thr, node.default_left = f, thr, dl
+    node.feature, node.thr, node.default_left, node.gain = f, thr, dl, gain
     node.left = _grow(x, g, h, idx[left], depth + 1, max_depth, lam, mcw, splitter)
     node.right = _grow(x, g, h, idx[~left], depth + 1, max_depth, lam, mcw, splitter)
     return node
@@ -134,7 +136,8 @@ def _predict_tree(node, x):
 
 def train_numpy(x, y, *, method="hist", n_rounds=20, max_depth=6, lr=0.3,
                 max_bins=256, objective="binary:logistic", lam=1.0, mcw=1.0):
-    """Returns (predict_fn, margins) after training."""
+    """Returns (predict_fn, margins) after training; `predict_fn.trees`
+    holds the per-round root `_Node`s."""
     n = len(x)
     margins = np.zeros((n, 1), np.float64)
     if objective == "reg:squarederror":
@@ -171,4 +174,5 @@ def train_numpy(x, y, *, method="hist", n_rounds=20, max_depth=6, lr=0.3,
             m += lr * _predict_tree(t, xq)
         return m
 
+    predict.trees = trees
     return predict, margins

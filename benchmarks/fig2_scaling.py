@@ -1,12 +1,16 @@
 """Paper Figure 2: runtime scaling with device count (airline dataset).
 
-The container has ONE physical core, so wall-clock cannot show real
-speedup; what CAN be measured faithfully is the Algorithm-1 distribution
-itself: a rows x devices grid recording rows/s and the per-round
-communication profile (wire bytes, collective calls, compression fallbacks
-— `Booster.comm_stats`, DESIGN.md §15) for each collective strategy
-(psum / ring / hier) and compression mode (f32 / f16 / q16). Each cell
-runs in a subprocess (XLA_FLAGS must precede jax init).
+A rows x devices grid recording rows/s and the per-round communication
+profile (wire bytes, collective calls, compression fallbacks —
+`Booster.comm_stats`, DESIGN.md §15) for each collective strategy
+(psum / ring / hier) and compression mode (f32 / f16 / q16).
+
+The whole grid runs in this one process: the cell with p devices trains on
+a ("data",) mesh of the first p of `jax.devices()`. On a TPU host those are
+chips; with JAX_PLATFORMS=cpu the script re-execs itself with
+`--xla_force_host_platform_device_count` set to the largest p, and the
+devices are virtual (then rows/s is not a speedup claim). A cell that asks
+for more devices than exist, or that fails, fails the script.
 
 `--merge-into BENCH_pipeline.json` folds the results into the shared BENCH
 file as a `scaling` section, including the headline comm-bytes reduction of
@@ -17,40 +21,8 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
-import textwrap
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-_SCRIPT = """
-import time, json
-import numpy as np, jax, jax.numpy as jnp
-from repro.core import Booster, BoosterConfig, DeviceDMatrix
-from repro.data import make_dataset
-from repro.jaxcompat import make_mesh
-
-p = {p}
-x, y, spec = make_dataset("airline", n_rows={rows})
-cfg = BoosterConfig(n_rounds={rounds}, max_depth=6, max_bins=256,
-                    objective=spec.objective)
-mesh = make_mesh((p,), ("data",))
-dtrain = DeviceDMatrix(x, label=y)
-fit_kw = dict(mesh=mesh, collective={collective!r},
-              compression={compression!r})
-# untimed warm-up fit compiles the round program
-Booster(BoosterConfig(n_rounds=1, max_depth=6, max_bins=256,
-                      objective=spec.objective)).fit(dtrain, **fit_kw)
-t0 = time.perf_counter()
-bst = Booster(cfg).fit(dtrain, **fit_kw)
-jax.block_until_ready(bst.margins)
-dt = time.perf_counter() - t0
-rec = dict(p=p, rows={rows}, time_s=dt, rows_per_device=len(x)//p,
-           rows_per_s=len(x) * {rounds} / dt, collective={collective!r},
-           compression={compression!r})
-rec.update(bst.comm_stats)
-print(json.dumps(rec))
-"""
+import time
 
 
 def allreduce_bytes_per_round(max_depth=6, n_features=13, max_bins=256):
@@ -62,25 +34,33 @@ def allreduce_bytes_per_round(max_depth=6, n_features=13, max_bins=256):
     return total
 
 
-def _cell(p, rows, rounds, collective, compression):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-    env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    res = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(_SCRIPT.format(
-            p=p, rows=rows, rounds=rounds, collective=collective,
-            compression=compression))],
-        capture_output=True, text=True, timeout=1200, env=env,
-    )
-    if res.returncode != 0:
-        return {"p": p, "rows": rows, "collective": collective,
-                "compression": compression, "error": res.stderr[-300:]}
-    rec = json.loads(res.stdout.strip().splitlines()[-1])
+def _cell(dtrain, objective, p, rounds, collective, compression):
+    import jax
+
+    from repro.core import Booster, BoosterConfig
+    from repro.dist import make_mesh
+
+    mesh = make_mesh((p,), ("data",), devices=jax.devices()[:p])
+    fit_kw = dict(mesh=mesh, collective=collective, compression=compression)
+    # untimed warm-up fit compiles the round program
+    Booster(BoosterConfig(n_rounds=1, max_depth=6, max_bins=256,
+                          objective=objective)).fit(dtrain, **fit_kw)
+    cfg = BoosterConfig(n_rounds=rounds, max_depth=6, max_bins=256,
+                        objective=objective)
+    t0 = time.perf_counter()
+    bst = Booster(cfg).fit(dtrain, **fit_kw)
+    jax.block_until_ready(bst.margins)
+    dt = time.perf_counter() - t0
+    rows = dtrain.n_rows
+    rec = dict(p=p, rows=rows, time_s=dt, rows_per_device=rows // p,
+               rows_per_s=rows * rounds / dt, collective=collective,
+               compression=compression)
+    rec.update(bst.comm_stats)
     rec["hist_bytes_per_round"] = sum(rec.pop("hist_bytes_per_level"))
     return rec
 
 
-def run(rows_list=(32_768,), rounds=5, device_counts=(1, 2, 4, 8),
+def run(rows_list=(32_768,), rounds=5, device_counts=(1, 2, 4),
         collectives=("psum", "ring", "hier"),
         compressions=(None, "q16")):
     """The rows x devices x (collective, compression) grid.
@@ -89,8 +69,21 @@ def run(rows_list=(32_768,), rounds=5, device_counts=(1, 2, 4, 8),
     (the strategy whose wire dtype actually narrows). p=1 runs only psum
     f32 (the single-device baseline row).
     """
+    import jax
+
+    from repro.core import DeviceDMatrix
+    from repro.data import make_dataset
+
+    n_dev = len(jax.devices())
+    if max(device_counts) > n_dev:
+        raise SystemExit(
+            f"--devices {max(device_counts)} needs that many devices; found "
+            f"{n_dev} {jax.devices()[0].platform} device(s)"
+        )
     grid = []
     for rows in rows_list:
+        x, y, spec = make_dataset("airline", n_rows=rows)
+        dtrain = DeviceDMatrix(x, label=y)
         for p in device_counts:
             cells = [("psum", None)]
             if p > 1:
@@ -98,18 +91,18 @@ def run(rows_list=(32_768,), rounds=5, device_counts=(1, 2, 4, 8),
                 cells += [("ring", comp) for comp in compressions
                           if comp is not None]
             for coll, comp in cells:
-                grid.append(_cell(p, rows, rounds, coll, comp))
+                grid.append(_cell(dtrain, spec.objective, p, rounds, coll,
+                                  comp))
     return grid
 
 
 def summarise(grid):
     """Headline: compressed ring vs exact f32 ring at the largest grid cell
     — histogram-payload and total wire-byte reduction factors."""
-    ok = [g for g in grid if "error" not in g]
-    ring_f32 = {(g["rows"], g["p"]): g for g in ok
+    ring_f32 = {(g["rows"], g["p"]): g for g in grid
                 if g["collective"] == "ring" and g["compression"] is None}
     best = None
-    for g in ok:
+    for g in grid:
         if g["compression"] is None:
             continue
         ref = ring_f32.get((g["rows"], g["p"]))
@@ -150,7 +143,7 @@ def merge_into(path, section):
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--rows", type=int, nargs="+", default=[32_768])
-    ap.add_argument("--devices", type=int, nargs="+", default=[1, 2, 4, 8])
+    ap.add_argument("--devices", type=int, nargs="+", default=[1, 2, 4])
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--compressions", nargs="+", default=["q16", "f16"])
     ap.add_argument("--out", default=None, help="write the grid json here")
@@ -158,24 +151,37 @@ def main(argv=None):
                     help="BENCH json to receive the `scaling` section")
     args = ap.parse_args(argv)
 
+    flag = "--xla_force_host_platform_device_count"
+    if (os.environ.get("JAX_PLATFORMS") == "cpu"
+            and flag not in os.environ.get("XLA_FLAGS", "")):
+        os.environ["XLA_FLAGS"] = (
+            f"{os.environ.get('XLA_FLAGS', '')} {flag}={max(args.devices)}"
+        ).strip()
+        os.execv(sys.executable, [sys.executable, *sys.argv])
+
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    import jax
+
     grid = run(rows_list=tuple(args.rows), rounds=args.rounds,
                device_counts=tuple(args.devices),
                compressions=tuple(args.compressions))
-    print("# Figure 2 grid (airline-shaped, virtual devices on 1 core):")
+    dev = jax.devices()[0]
+    print(f"# Figure 2 grid (airline-shaped) on {dev.platform} "
+          f"{dev.device_kind} x{len(jax.devices())}:")
     print("rows,devices,collective,compression,time_s,rows_per_s,"
           "bytes_per_round,hist_bytes_per_round,fallbacks")
     for g in grid:
-        if "error" in g:
-            print(f"{g['rows']},{g['p']},{g['collective']},"
-                  f"{g['compression']},ERROR,{g['error'][:80]}")
-        else:
-            print(f"{g['rows']},{g['p']},{g['collective']},"
-                  f"{g['compression']},{g['time_s']:.2f},"
-                  f"{g['rows_per_s']:.0f},{g['bytes_per_round']},"
-                  f"{g['hist_bytes_per_round']},{g['fallback_events']}")
+        print(f"{g['rows']},{g['p']},{g['collective']},"
+              f"{g['compression']},{g['time_s']:.2f},"
+              f"{g['rows_per_s']:.0f},{g['bytes_per_round']},"
+              f"{g['hist_bytes_per_round']},{g['fallback_events']}")
     section = {
-        "note": "virtual devices on one core: rows/s is NOT a speedup "
-                "claim; comm bytes/round is the faithful signal "
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "note": "on virtual CPU devices rows/s is NOT a speedup claim; "
+                "comm bytes/round is the faithful signal "
                 "(Booster.comm_stats, DESIGN.md §15)",
         "rounds": args.rounds,
         "grid": grid,

@@ -66,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Booster, DeviceDMatrix, ExternalDMatrix
 from repro.core import booster as B
 from repro.core import compress as C
@@ -859,6 +860,7 @@ def run(rows, features, max_bins, max_depth, n_rounds,
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=1_000_000)
     ap.add_argument("--features", type=int, default=50)

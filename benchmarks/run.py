@@ -29,8 +29,8 @@ def main() -> None:
             from benchmarks import table2
             table2.main(rows=args.rows)
         elif sec == "compression":
-            from benchmarks import compression
-            compression.main()
+            from benchmarks import compression_ratio
+            compression_ratio.main()
         elif sec == "fig2":
             from benchmarks import fig2_scaling
             fig2_scaling.main()

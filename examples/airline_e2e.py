@@ -36,7 +36,7 @@ n_tr = (n_tr // args.devices) * args.devices  # shard-divisible (no-op at 1)
 
 mesh = None
 if args.devices > 1:
-    from repro.jaxcompat import make_mesh
+    from repro.dist import make_mesh
     mesh = make_mesh((args.devices,), ("data",))
 
 t0 = time.perf_counter()

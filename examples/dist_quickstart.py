@@ -19,7 +19,7 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.core import Booster, DeviceDMatrix  # noqa: E402
 from repro.dist import sharded_sketch_cuts  # noqa: E402
-from repro.jaxcompat import make_mesh  # noqa: E402
+from repro.dist import make_mesh  # noqa: E402
 
 rng = np.random.default_rng(0)
 n, f = 8_192, 10

@@ -246,8 +246,8 @@ class DeviceDMatrix:
         self.group_ids = (
             None if group_ids is None else jnp.asarray(group_ids, jnp.int32)
         )
-        # Per-shard re-packings built by the distributed strategy, keyed by
-        # shard count — paid once per (matrix, mesh size), not per fit.
+        # Per-shard re-packings placed on a mesh (`sharded_packed`), keyed
+        # by sharding — paid once per (matrix, mesh), not per fit.
         self._shard_pack_cache: dict = {}
         if self.label is not None and self.label.shape[0] != self.n_rows:
             raise ValueError(
@@ -316,6 +316,27 @@ class DeviceDMatrix:
     def packed_bins(self) -> C.PackedBins:
         """The traced (jit-flowable) view consumed by the training scan."""
         return self.matrix.as_packed_bins()
+
+    def sharded_packed(self, n_shards: int, sharding) -> jax.Array:
+        """The packed words re-packed per row shard, so each shard's words
+        decode independently, and placed with `sharding` (words axis split
+        over the data axes) — the matrix a row-sharded fit trains on.
+        Cached per sharding: the dense-bins transient (the matrix DESIGN.md
+        §2 bans from steady state) exists once per mesh, not once per fit.
+        """
+        data = self._shard_pack_cache.get(sharding)
+        if data is None:
+            bins = self.matrix.unpack()
+            n_per = self.n_rows // n_shards
+            data = jnp.concatenate(
+                [C.pack(bins[i * n_per : (i + 1) * n_per], self.bits)
+                 for i in range(n_shards)],
+                axis=1,
+            )  # (F, n_shards * W)
+            data = self._shard_pack_cache[sharding] = jax.device_put(
+                data, sharding
+            )
+        return data
 
     def compression_ratio(self) -> float:
         return self.matrix.compression_ratio()
@@ -748,16 +769,13 @@ class ExternalDMatrix:
         "auto" resolves to "stream" only when the backing device reports a
         memory limit and the compressed stack would occupy more than half
         of it (leaving headroom for gradients, histograms and transients);
-        anywhere the limit is unknown — notably CPU backends — it resolves
-        to "resident", the proven compiled-scan path.
+        anywhere the limit is unknown — CPU backends report no memory
+        stats — it resolves to "resident", the proven compiled-scan path.
         """
         if self.paging != "auto":
             return self.paging
-        try:
-            stats = jax.devices()[0].memory_stats()
-            limit = (stats or {}).get("bytes_limit")
-        except Exception:
-            limit = None
+        stats = jax.devices()[0].memory_stats()
+        limit = (stats or {}).get("bytes_limit")
         if limit and self.nbytes_host > 0.5 * limit:
             return "stream"
         return "resident"

@@ -50,9 +50,9 @@ def select_cuts_from_sorted(
 ) -> jax.Array:
     """Selection stage of compute_cuts: weighted-rank pick + interpolation
     + dedup over pre-sorted columns. Split out so the sort stage can
-    dispatch independently (host sort on CPU, device sort / Pallas
-    selection kernel elsewhere — kernels/quantile_cuts.py reproduces this
-    arithmetic operation for operation and is parity-tested against it).
+    dispatch independently (host sort on CPU, device sort elsewhere).
+    kernels/quantile_cuts.py reproduces this arithmetic operation for
+    operation and is parity-tested against it in interpret mode.
     """
     nvb = n_value_bins(max_bins)
     n = srt.shape[0]
@@ -94,8 +94,7 @@ def compute_cuts(x: jax.Array, max_bins: int = DEFAULT_MAX_BINS) -> jax.Array:
     Dispatches through kernels.ops.compute_cuts_op: the sort stage runs on
     the host (np.sort) when the backend is CPU — an order of magnitude
     faster than XLA's CPU sort at 1M rows, see BENCH `kernels` section —
-    and on device otherwise, where the selection stage additionally uses
-    the Pallas kernel when the matrix fits VMEM. Every path produces
+    and on device otherwise; the selection stage is shared. Every path produces
     bit-identical cuts to `compute_cuts_reference` (tested): the sorted
     multiset is the same array no matter who sorts it, and the selection
     arithmetic is shared.
@@ -110,8 +109,8 @@ def compute_cuts_reference(
     x: jax.Array, max_bins: int = DEFAULT_MAX_BINS
 ) -> jax.Array:
     """The original single-pass compute_cuts (vmapped per-feature device
-    sort + selection). Kept as the oracle for the dispatching fast path and
-    the Pallas selection kernel; also exercises the pure-jnp route on
+    sort + selection). Kept as the oracle for the dispatching fast path;
+    also exercises the pure-jnp route on
     backends without host callbacks.
     """
     x = x.astype(jnp.float32)

@@ -13,7 +13,8 @@ surface:
   * `sharded_sketch_cuts` / `tree_merge` — data-parallel quantile sketch
     build (device-sorted shards, log-depth merge; paper §quantiles).
   * `RoundInputs` / `make_distributed_round` / `make_chunk_runner` — the
-    shard_map training round behind `fit(mesh=)`.
+    shard_map training round behind `fit(mesh=)`; `make_mesh` builds the
+    (Auto-axis) mesh it expects.
 """
 from repro.dist.collective import (
     Collective,
@@ -30,6 +31,7 @@ from repro.dist.runner import (
     RoundInputs,
     make_chunk_runner,
     make_distributed_round,
+    make_mesh,
     train_distributed,
 )
 from repro.dist.sketch import (
@@ -48,6 +50,7 @@ __all__ = [
     "get_collective",
     "make_chunk_runner",
     "make_distributed_round",
+    "make_mesh",
     "register_collective",
     "round_comm_stats",
     "sharded_sketch_cuts",

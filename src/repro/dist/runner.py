@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jaxcompat
 from repro.core import compress as C
 from repro.core import objectives as O
 from repro.core import resilience as RES
@@ -66,6 +65,17 @@ class RoundInputs(NamedTuple):
             cuts=P(),
             rkey=P() if stochastic else None,
         )
+
+
+def make_mesh(axis_shapes, axis_names, devices=None) -> jax.sharding.Mesh:
+    """`jax.make_mesh` with every axis `Auto`: the sharded round is written
+    for shard_map plus compiler-propagated shardings, not explicit-axis
+    sharding-in-types (jax.make_mesh's default)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
 
 
 # Compiled per-round shard_map programs and eval-margin updaters, keyed by
@@ -235,11 +245,12 @@ def make_distributed_round(
         out_specs = out_specs + (P(),)  # all-reduced ok flag, replicated
     if compressed:
         out_specs = out_specs + (P(),)  # fallback tally, replicated
-    shard_fn = jaxcompat.shard_map(
+    shard_fn = jax.shard_map(
         round_body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
+        check_vma=False,
     )
     fn = _ROUND_FN_CACHE[key] = jax.jit(shard_fn)
     return fn
@@ -319,22 +330,11 @@ def make_chunk_runner(
         data = dmat.packed_bins().packed
         chunk_rows = dmat.chunk_rows
     elif cfg.compress_matrix:
-        # Re-pack per shard so each shard's words decode independently.
-        # Cached on the DeviceDMatrix: the dense-bins transient (the matrix
-        # DESIGN.md §2 bans from steady state) exists once per shard count,
-        # not once per fit.
+        # Re-packed per shard and placed by the DeviceDMatrix (cached there).
         bits = dmat.bits
         n_per = n // n_shards
         chunk_rows = None
-        data = dmat._shard_pack_cache.get(n_shards)
-        if data is None:
-            bins = dmat.matrix.unpack()
-            packed_shards = [
-                C.pack(bins[i * n_per : (i + 1) * n_per], bits)
-                for i in range(n_shards)
-            ]
-            data = jnp.concatenate(packed_shards, axis=1)  # (F, n_shards*W)
-            dmat._shard_pack_cache[n_shards] = data
+        data = None
     else:
         data = dmat.matrix.unpack()
         bits, n_per, chunk_rows = None, None, None
@@ -349,7 +349,10 @@ def make_chunk_runner(
         data_spec = P(axes, None)
     data_sharding = jax.NamedSharding(mesh, data_spec)
     y = jax.device_put(dmat.label, row_sharding)
-    data = jax.device_put(data, data_sharding)
+    if data is None:
+        data = dmat.sharded_packed(n_shards, data_sharding)
+    else:
+        data = jax.device_put(data, data_sharding)
     round_fn = make_distributed_round(
         cfg, obj, mesh, data_axes, n_rows_per_shard=n_per, bits=bits,
         chunk_rows=chunk_rows, collective=coll,

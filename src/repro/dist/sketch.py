@@ -33,7 +33,6 @@ from repro.core.quantile import (
     DEFAULT_MAX_BINS,
     StreamingQuantileSketch,
 )
-from repro.jaxcompat import shard_map
 
 from jax.sharding import PartitionSpec as P
 
@@ -85,11 +84,12 @@ def _device_sort_phase(x, mesh, data_axes):
         nv = jnp.sum(finite, axis=0, dtype=jnp.int32)[None, :]
         return srt, nv
 
-    srt, nv = shard_map(
+    srt, nv = jax.shard_map(
         shard_fn,
-        mesh,
+        mesh=mesh,
         in_specs=(P(axes, None),),
         out_specs=(P(axes, None), P(axes, None)),
+        check_vma=False,
     )(jnp.asarray(x, jnp.float32))
     srt_h = np.asarray(jax.device_get(srt)).reshape(n_shards, n // n_shards,
                                                     x.shape[1])

@@ -148,22 +148,45 @@ def histogram_packed(
 
 
 # --- privatised kernel with explicit DMA pipelining (DESIGN.md §16) ----------
+#
+# Layout, chosen so that the TPU compiler accepts every block and DMA:
+#   * packed words are staged as (F_BLK=8, W_BLK=128) tiles — sublane- and
+#     lane-aligned;
+#   * (g, h) and positions are staged lane-major, (2, ROWS_BLK) and
+#     (1, ROWS_BLK). Within each row chunk they are pre-permuted symbol-slot
+#     major (`_slot_major`), so that slot s of a word tile —
+#     `(words >> s*bits) & mask`, rows w*spw + s — lines up with the
+#     contiguous lane range [s*W_BLK, (s+1)*W_BLK) of the staged (g, h) and
+#     positions, and the unpack needs no lane interleave;
+#   * the histogram factorises over (node, bin): per feature,
+#       hist[(g|h), node, bin] = (onehot(pos) * (g|h)) @ onehot(bin).T
+#     a (2*NODES_PAD, W_BLK) x (W_BLK, B) MXU matmul per word slot, with
+#     `bin` on the lane axis of the (F_BLK, 2*NODES_PAD, B) accumulator.
+#     Rows parked in the dump slot (pos == n_nodes) match no node row.
+
+
+def _slot_major(v: jax.Array, w_blk: int, spw: int) -> jax.Array:
+    """(..., N) row-ordered -> (..., N) with each ROWS_BLK chunk reordered
+    from row order (w*spw + s) to slot-major order (s*W_BLK + w)."""
+    lead = v.shape[:-1]
+    n_chunks = v.shape[-1] // (w_blk * spw)
+    v = v.reshape(*lead, n_chunks, w_blk, spw)
+    return jnp.swapaxes(v, -1, -2).reshape(*lead, n_chunks * w_blk * spw)
 
 
 def _private_kernel(
     packed_hbm,  # (F_pad, W_pad) uint32, whole array in HBM/ANY
-    gh_hbm,  # (N_pad, 2) f32, whole array
-    pos_hbm,  # (N_pad, 1) i32, whole array
-    out_ref,  # (1, F_BLK, width, 2) f32 — this program's partial histogram
+    gh_hbm,  # (2, N_pad) f32, slot-major within each row chunk
+    pos_hbm,  # (1, N_pad) i32, slot-major within each row chunk
+    out_ref,  # (1, F_BLK, 2*NODES_PAD, B) f32 — this program's partial
     words_buf,  # VMEM (buffer_depth, F_BLK, W_BLK) uint32 scratch
-    gh_buf,  # VMEM (buffer_depth, ROWS_BLK, 2) f32 scratch
-    pos_buf,  # VMEM (buffer_depth, ROWS_BLK, 1) i32 scratch
-    acc_ref,  # VMEM (F_BLK, width, 2) f32 scratch — the privatised histogram
+    gh_buf,  # VMEM (buffer_depth, 2, ROWS_BLK) f32 scratch
+    pos_buf,  # VMEM (buffer_depth, 1, ROWS_BLK) i32 scratch
     sem,  # DMA semaphores (3, buffer_depth)
     *,
     bits: int,
     max_bins: int,
-    width: int,
+    nodes_pad: int,
     f_blk: int,
     w_blk: int,
     chunks_per_private: int,
@@ -185,10 +208,12 @@ def _private_kernel(
                 sem.at[0, slot],
             ),
             pltpu.make_async_copy(
-                gh_hbm.at[pl.ds(row0, rows_blk), :], gh_buf.at[slot], sem.at[1, slot]
+                gh_hbm.at[:, pl.ds(row0, rows_blk)], gh_buf.at[slot],
+                sem.at[1, slot],
             ),
             pltpu.make_async_copy(
-                pos_hbm.at[pl.ds(row0, rows_blk), :], pos_buf.at[slot], sem.at[2, slot]
+                pos_hbm.at[:, pl.ds(row0, rows_blk)], pos_buf.at[slot],
+                sem.at[2, slot],
             ),
         )
 
@@ -200,12 +225,12 @@ def _private_kernel(
         for c in copies(chunk, slot):
             c.wait()
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    out_ref[...] = jnp.zeros_like(out_ref)
     start(0, 0)
 
-    shifts = (jnp.arange(spw, dtype=jnp.uint32) * bits)[None, None, :]
     mask = jnp.uint32((1 << bits) - 1)
-    iota = jnp.arange(width, dtype=jnp.int32)[None, :]
+    node_iota = jax.lax.broadcasted_iota(jnp.int32, (nodes_pad, w_blk), 0)
+    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (max_bins, w_blk), 0)
 
     def body(chunk, carry):
         slot = chunk % buffer_depth
@@ -220,18 +245,37 @@ def _private_kernel(
 
         wait(chunk, slot)
 
-        words = words_buf[slot]  # (F_BLK, W_BLK)
-        bins = ((words[:, :, None] >> shifts) & mask).reshape(f_blk, rows_blk)
-        bins = bins.astype(jnp.int32)
-        gh = gh_buf[slot]  # (ROWS_BLK, 2)
-        # pos <= n_nodes always (dump slot included in width), no masking.
-        base = pos_buf[slot][:, 0] * max_bins  # (ROWS_BLK,)
-
-        for f in range(f_blk):  # static unroll: F_BLK small
-            onehot = ((base + bins[f])[:, None] == iota).astype(jnp.float32)
-            acc_ref[f, :, :] += jnp.dot(
-                onehot.T, gh, preferred_element_type=jnp.float32
+        # Per word slot s, the (node one-hot * g | h) left operand, shared
+        # by every feature. Lane slices are taken from the refs: Mosaic
+        # refuses to broadcast a row lane-sliced out of a loaded value.
+        lhs = []
+        for s in range(spw):
+            lanes = pl.ds(s * w_blk, w_blk)
+            node_hot = (node_iota == pos_buf[slot, :, lanes]).astype(
+                jnp.float32
             )
+            lhs.append(jnp.concatenate(
+                [node_hot * gh_buf[slot, 0:1, lanes],
+                 node_hot * gh_buf[slot, 1:2, lanes]], axis=0
+            ))  # (2*NODES_PAD, W_BLK)
+
+        def feature(fi, c):
+            words = words_buf[slot, pl.ds(fi, 1), :]  # (1, W_BLK)
+            acc = out_ref[0, fi]
+            for s in range(spw):
+                bins = ((words >> jnp.uint32(s * bits)) & mask).astype(
+                    jnp.int32
+                )
+                bin_hot = (bin_iota == bins).astype(jnp.float32)
+                acc += jax.lax.dot_general(
+                    lhs[s], bin_hot, (((1,), (1,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32,
+                )  # (2*NODES_PAD, B)
+            out_ref[0, fi] = acc
+            return c
+
+        jax.lax.fori_loop(0, f_blk, feature, jnp.int32(0))
 
         # Single-buffer pipeline: the slot is free only now.
         if buffer_depth == 1:
@@ -243,7 +287,6 @@ def _private_kernel(
         return carry
 
     jax.lax.fori_loop(0, chunks_per_private, body, jnp.int32(0))
-    out_ref[0] = acc_ref[...]
 
 
 def _tree_add(parts: jax.Array) -> jax.Array:
@@ -271,7 +314,7 @@ def build_histograms_packed_kernel(
     bits: int,
     *,
     f_blk: int = 8,
-    w_blk: int = 64,
+    w_blk: int = 128,
     n_private: int = 8,
     buffer_depth: int = 2,
     interpret: bool | None = None,
@@ -279,19 +322,18 @@ def build_histograms_packed_kernel(
     """Privatised packed-histogram kernel: grid (row_groups, feature_blocks).
 
     The CUDA kernel's shared-memory privatisation (paper §2.3) mapped to
-    TPU: each of `n_private` row groups accumulates its own full
-    (F_BLK, (n_nodes+1)*max_bins, 2) histogram in a VMEM scratch
-    accumulator — never contending with other groups — while packed words,
-    (g, h) pairs and positions are staged HBM->VMEM with explicit
-    `make_async_copy` DMAs, `buffer_depth` chunks in flight (1 = serial,
-    2 = classic double buffering, 4 = deeper pipeline; BENCH sweeps all
-    three). The per-group partials are merged by a log-depth tree-add
-    epilogue (`_tree_add`), the analogue of the CUDA grid-wide flush.
+    TPU: each of `n_private` row groups accumulates its own
+    (F_BLK, 2*NODES_PAD, B) histogram in its VMEM output block — never
+    contending with other groups — while packed words, (g, h) pairs and
+    positions are staged HBM->VMEM with explicit `make_async_copy` DMAs,
+    `buffer_depth` chunks in flight (1 = serial, 2 = classic double
+    buffering, 4 = deeper pipeline). The per-group partials are merged by a
+    log-depth tree-add epilogue (`_tree_add`), the analogue of the CUDA
+    grid-wide flush. On the TPU `w_blk` must be a multiple of 128.
 
-    VMEM bound: the accumulator is f_blk * (n_nodes+1) * max_bins * 2 * 4
-    bytes (~0.5 MB at depth 6 defaults) plus a (ROWS_BLK, width) one-hot
-    transient, which caps practical n_nodes at ~32 (DESIGN.md §16); deeper
-    levels use the XLA feature-major builder instead.
+    VMEM: the output block is f_blk * 2 * NODES_PAD * max_bins * 4 bytes
+    (0.5 MB at n_nodes 32, 256 bins), double-buffered, plus one
+    (max_bins, W_BLK) bin one-hot per feature.
 
     Returns hist (n_nodes, F, max_bins, 2) f32.
     """
@@ -301,7 +343,7 @@ def build_histograms_packed_kernel(
     n = gh.shape[0]
     spw = 32 // bits
     rows_blk = w_blk * spw
-    width = (n_nodes + 1) * max_bins
+    nodes_pad = max(8, -(-n_nodes // 8) * 8)
 
     n_fblk = -(-f // f_blk)
     f_pad = n_fblk * f_blk - f
@@ -310,20 +352,25 @@ def build_histograms_packed_kernel(
     n_rows_padded = w_padded * spw
 
     packed_p = jnp.pad(packed, ((0, f_pad), (0, w_padded - w)))
-    gh_p = jnp.pad(gh, ((0, n_rows_padded - n), (0, 0)))
-    # Padding rows -> dump slot n_nodes (sliced off below), like inactive
-    # rows; clamp real inactive markers the same way.
-    pos_p = jnp.pad(
-        jnp.minimum(positions, n_nodes).astype(jnp.int32),
-        (0, n_rows_padded - n),
-        constant_values=n_nodes,
-    )[:, None]
+    gh_p = _slot_major(
+        jnp.pad(gh, ((0, n_rows_padded - n), (0, 0))).T, w_blk, spw
+    )
+    # Padding rows -> dump slot n_nodes, like inactive rows; clamp real
+    # inactive markers the same way.
+    pos_p = _slot_major(
+        jnp.pad(
+            jnp.minimum(positions, n_nodes).astype(jnp.int32),
+            (0, n_rows_padded - n),
+            constant_values=n_nodes,
+        )[None, :],
+        w_blk, spw,
+    )
 
     kern = functools.partial(
         _private_kernel,
         bits=bits,
         max_bins=max_bins,
-        width=width,
+        nodes_pad=nodes_pad,
         f_blk=f_blk,
         w_blk=w_blk,
         chunks_per_private=chunks_per_private,
@@ -333,25 +380,25 @@ def build_histograms_packed_kernel(
         kern,
         grid=(n_private, n_fblk),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (1, f_blk, width, 2), lambda pid, fb: (pid, fb, 0, 0)
+            (1, f_blk, 2 * nodes_pad, max_bins),
+            lambda pid, fb: (pid, fb, 0, 0),
         ),
         out_shape=jax.ShapeDtypeStruct(
-            (n_private, n_fblk * f_blk, width, 2), jnp.float32
+            (n_private, n_fblk * f_blk, 2 * nodes_pad, max_bins), jnp.float32
         ),
         scratch_shapes=[
             pltpu.VMEM((buffer_depth, f_blk, w_blk), jnp.uint32),
-            pltpu.VMEM((buffer_depth, rows_blk, 2), jnp.float32),
-            pltpu.VMEM((buffer_depth, rows_blk, 1), jnp.int32),
-            pltpu.VMEM((f_blk, width, 2), jnp.float32),
+            pltpu.VMEM((buffer_depth, 2, rows_blk), jnp.float32),
+            pltpu.VMEM((buffer_depth, 1, rows_blk), jnp.int32),
             pltpu.SemaphoreType.DMA((3, buffer_depth)),
         ],
         interpret=interpret,
     )(packed_p, gh_p, pos_p)
-    merged = _tree_add(partials)  # (F_pad, width, 2)
-    hist = merged.reshape(n_fblk * f_blk, n_nodes + 1, max_bins, 2)
-    return hist.transpose(1, 0, 2, 3)[:n_nodes, :f]
+    merged = _tree_add(partials)  # (F_pad, 2*NODES_PAD, B)
+    hist = merged.reshape(n_fblk * f_blk, 2, nodes_pad, max_bins)
+    return hist.transpose(2, 0, 3, 1)[:n_nodes, :f]

@@ -13,9 +13,8 @@ Two hist_builder entry points for grow_tree(hist_builder=...):
 This module is also where quantile-cut construction picks its backend
 (`compute_cuts_op`): the sort stage goes to the host's np.sort on CPU (the
 XLA CPU sort is ~an order of magnitude slower at 1M rows) and to the XLA
-device sort elsewhere; the selection stage goes to the Pallas kernel
-(kernels/quantile_cuts.py) on accelerators when the sorted block fits
-VMEM, and to the shared XLA selection otherwise. All paths emit
+device sort elsewhere; the selection stage is the shared XLA selection
+(`core.quantile.select_cuts_from_sorted`) on every backend. All paths emit
 bit-identical cuts (tests/test_quantile.py).
 """
 from __future__ import annotations
@@ -29,14 +28,9 @@ import numpy as np
 from repro.core import compress as C
 from repro.core import quantile as Q
 from repro.kernels.histogram import histogram_packed, build_histograms_packed_kernel
-from repro.kernels.quantile_cuts import quantile_cuts_from_sorted
 from repro.kernels.split_scan import split_scan
 from repro.kernels.decompress import decompress
 from repro.kernels.ensemble_traversal import ensemble_margins_kernel
-
-# Largest row count the cut-selection kernel keeps resident per feature
-# block: (rows, F_BLK=8) f32 -> 4 MB at this bound, within VMEM budget.
-CUTS_KERNEL_MAX_ROWS = 131072
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "max_bins", "bits"))
@@ -104,16 +98,10 @@ def _cuts_prep(x: jax.Array):
 
 @functools.partial(jax.jit, static_argnames=("max_bins",))
 def _compute_cuts_device(x: jax.Array, max_bins: int) -> jax.Array:
-    """Fully on-device cut construction: XLA column sort, then the Pallas
-    selection kernel when the sorted block fits VMEM, else the shared XLA
-    selection."""
+    """Fully on-device cut construction: XLA column sort, then the shared
+    XLA selection."""
     filled, n_valid = _cuts_prep(x)
     srt = jnp.sort(filled, axis=0)
-    if (
-        jax.default_backend() != "cpu"
-        and srt.shape[0] <= CUTS_KERNEL_MAX_ROWS
-    ):
-        return quantile_cuts_from_sorted(srt, n_valid, max_bins)
     return Q.select_cuts_from_sorted(srt, n_valid, max_bins)
 
 

@@ -23,10 +23,13 @@ contract `lo + frac*(hi-lo)` into an FMA where the kernel's evaluation
 does not — which can additionally flip a floor() at an exact integer rank
 boundary and select the neighbouring order statistic (still a valid
 boundary for the same equal-mass bin). The final ascending re-sort of the
-candidate vector is left to the caller, as in the reference. Rows must fit in VMEM per feature block (the
-ops-layer dispatch bounds this; larger matrices use the XLA selection).
-The CPU training path never takes this kernel (host sort + shared XLA
-selection there is bit-identical to the reference by construction).
+candidate vector is left to the caller, as in the reference.
+
+No training path calls this kernel: `kernels.ops.compute_cuts_op` uses the
+shared XLA selection on every backend. The TPU compiler refuses this
+kernel as written — its (n, F_BLK=8) block is not lane-aligned and the
+body gathers with `jnp.take` — so it runs only in interpret mode, in its
+parity tests.
 """
 from __future__ import annotations
 

@@ -12,13 +12,13 @@ from __future__ import annotations
 
 import jax
 
-from repro import jaxcompat
+from repro.dist import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jaxcompat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def batch_axes(mesh: jax.sharding.Mesh):

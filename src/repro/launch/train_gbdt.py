@@ -1,10 +1,12 @@
 """GBDT training driver — the paper's own end-to-end pipeline (Figure 1)
 behind the two-noun API: DeviceDMatrix (quantise once) + Booster.fit.
 
-Single-device by default; --devices N uses N virtual host devices and the
-shard_map/psum distributed strategy behind the same Booster.fit signature
-(Algorithm 1's multi-GPU path; set XLA_FLAGS by re-exec so the flag precedes
-jax init). Both paths produce the same Booster object.
+Single-device by default; --devices N shards rows over a ("data",) mesh of
+the first N devices and trains with the shard_map/psum strategy behind the
+same Booster.fit signature (Algorithm 1's multi-GPU path). On a TPU host
+those are N chips, all driven from this one process; with JAX_PLATFORMS=cpu
+they are N virtual host devices (the script re-execs itself so that
+XLA_FLAGS precedes jax init). Both paths produce the same Booster object.
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train_gbdt --dataset higgs \
@@ -37,11 +39,18 @@ def main():
     ap.add_argument("--checkpoint", default="")
     args = ap.parse_args()
 
-    if args.devices > 1 and "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    flag = "--xla_force_host_platform_device_count"
+    if (args.devices > 1 and os.environ.get("JAX_PLATFORMS") == "cpu"
+            and flag not in os.environ.get("XLA_FLAGS", "")):
         os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}"
+            f"{os.environ.get('XLA_FLAGS', '')} {flag}={args.devices}".strip()
         )
         os.execv(sys.executable, [sys.executable, "-m", "repro.launch.train_gbdt", *sys.argv[1:]])
+
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    import jax
 
     from repro.core import Booster, BoosterConfig, DeviceDMatrix
     from repro.data import make_dataset
@@ -67,8 +76,16 @@ def main():
 
     mesh = None
     if args.devices > 1:
-        from repro import jaxcompat
-        mesh = jaxcompat.make_mesh((args.devices,), ("data",))
+        from repro.dist import make_mesh
+
+        devices = jax.devices()
+        if len(devices) < args.devices:
+            raise SystemExit(
+                f"--devices {args.devices} needs {args.devices} devices; "
+                f"found {len(devices)} {devices[0].platform} device(s)"
+            )
+        mesh = make_mesh((args.devices,), ("data",),
+                         devices=devices[: args.devices])
 
     t0 = time.perf_counter()
     bst = Booster(cfg).fit(

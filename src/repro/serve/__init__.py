@@ -11,8 +11,8 @@ side: a dedicated batched-inference stack over the compact ensemble arena.
     `kernels.ensemble_traversal` with the XLA form as its parity oracle.
   * `engine`     — `PredictEngine`: shape-bucketed compiled predict caches
     (mixed request sizes pad up to a small static set of power-of-two row
-    buckets, so serving traffic never recompiles), donated output buffers,
-    optional persistent host staging, and per-call latency accounting
+    buckets, so serving traffic never recompiles), optional persistent
+    host staging, and per-call latency accounting
     (p50/p99, rows/s).
   * `interop`    — XGBoost model-format interop: load a real
     `xgboost.Booster` JSON into our arena (matching its predictions) and

@@ -10,10 +10,6 @@ request traffic rather than training:
     triggers a recompile (asserted by a trace counter the tests read).
     Padding rows are NaN — the legal missing marker, routed through default
     directions like any missing value — and are sliced off the output.
-  * Donated input blocks. Off CPU the padded device block is donated to the
-    compiled call (`donate_argnums`), letting XLA reuse its buffer for the
-    margin output instead of allocating fresh HBM per request. CPU backends
-    ignore donation, so it is gated to avoid the warning.
   * Persistent host staging. One preallocated float32 staging buffer per
     bucket: the request's rows are copied (and dtype-converted — the single
     float32 conversion on this path) into the buffer's head, the tail is
@@ -124,8 +120,7 @@ class PredictEngine:
                 )
                 return m if self._transform is None else self._transform(m)
 
-            donate = () if jax.default_backend() == "cpu" else (1,)
-            fn = jax.jit(traced, donate_argnums=donate)
+            fn = jax.jit(traced)
             self._compiled[bucket] = fn
         return fn
 

@@ -13,7 +13,7 @@ import textwrap
 import pytest
 
 from repro import dist
-from repro.jaxcompat import make_mesh
+from repro.dist import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -37,7 +37,7 @@ def test_every_collective_matches_single_device():
     out = _run("""
         import numpy as np, jax.numpy as jnp
         from repro.core import Booster, BoosterConfig, DeviceDMatrix
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         rng = np.random.default_rng(5)
         n, f = 2048, 8
         x = rng.normal(size=(n, f)).astype(np.float32)
@@ -80,7 +80,7 @@ def test_compressed_allreduce_trains_within_tolerance():
     out = _run("""
         import numpy as np, jax.numpy as jnp
         from repro.core import Booster, BoosterConfig, DeviceDMatrix
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         rng = np.random.default_rng(7)
         n, f = 4096, 10
         x = rng.normal(size=(n, f)).astype(np.float32)
@@ -134,7 +134,7 @@ def test_fallback_on_adversarial_gradients():
         import numpy as np, jax.numpy as jnp
         from repro import dist
         from repro.core import Booster, BoosterConfig, DeviceDMatrix
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         rng = np.random.default_rng(9)
         n, f = 2048, 6
         x = rng.normal(size=(n, f)).astype(np.float32)

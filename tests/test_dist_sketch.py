@@ -160,7 +160,7 @@ def test_device_phase_sharded_sketch():
         from repro.core import Booster, DeviceDMatrix
         from repro.core.quantile import compute_cuts
         from repro.dist import sharded_sketch_cuts
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         rng = np.random.default_rng(11)
         n, f = 4096, 6
         x = rng.normal(size=(n, f)).astype(np.float32)

@@ -39,7 +39,7 @@ def test_row_sharded_equals_single_device():
                             objective="binary:logistic", max_bins=32)
         dtrain = DeviceDMatrix(x, label=y, max_bins=cfg.max_bins)
         st = Booster(cfg).fit(dtrain)
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         mesh = make_mesh((8,), ("data",))
         bst = Booster(cfg).fit(dtrain, mesh=mesh)
         assert type(bst) is type(st)  # identical object shape out
@@ -73,7 +73,7 @@ def test_subsampled_row_sharded_equals_single_device():
                             seed=13)
         dtrain = DeviceDMatrix(x, label=y, max_bins=cfg.max_bins)
         st = Booster(cfg).fit(dtrain)
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         mesh = make_mesh((8,), ("data",))
         bst = Booster(cfg).fit(dtrain, mesh=mesh)
         assert bool(jnp.all(st.ensemble.feature == bst.ensemble.feature))
@@ -108,14 +108,14 @@ def test_feature_sharded_equals_single_device():
         bins = Q.quantize(jnp.asarray(x), cuts)
         p = jax.nn.sigmoid(jnp.zeros(n)); gh = jnp.stack([p - y, p*(1-p)], -1)
         ref = T.grow_tree(bins, gh, cuts, 4, 32)
-        from repro.jaxcompat import make_mesh, shard_map
+        from repro.dist import make_mesh
         mesh = make_mesh((4, 2), ("data", "model"))
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda b, g, c: T.grow_tree(b, g, c, 4, 32, axis_name="data",
                                         feature_axis="model"),
             mesh=mesh,
             in_specs=(P("data", "model"), P("data", None), P("model", None)),
-            out_specs=P()))
+            out_specs=P(), check_vma=False))
         tr = fn(bins, gh, cuts)
         assert bool(jnp.all(ref.feature == tr.feature))
         assert bool(jnp.all(ref.split_bin == tr.split_bin))
@@ -129,7 +129,7 @@ def test_hlo_analyzer_matches_analytic():
     out = _run("""
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         from repro.launch.hlo_analysis import analyze
         mesh = make_mesh((2, 4), ("data", "model"))
         D, L, B = 64, 4, 8

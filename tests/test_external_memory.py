@@ -236,7 +236,7 @@ def test_distributed_external_matches_single_device():
         """
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import Booster, BoosterConfig, ExternalDMatrix
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         rng = np.random.default_rng(2)
         n, f = 2048, 6
         x = rng.normal(size=(n, f)).astype(np.float32)

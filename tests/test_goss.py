@@ -192,7 +192,7 @@ def test_goss_sharded_equals_single_device():
     script = """
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import Booster, BoosterConfig, DeviceDMatrix
-        from repro.jaxcompat import make_mesh
+        from repro.dist import make_mesh
         rng = np.random.default_rng(4)
         n, f = 1024, 6
         x = rng.normal(size=(n, f)).astype(np.float32)

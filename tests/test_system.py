@@ -81,7 +81,8 @@ def test_gbdt_driver_cli(tmp_path):
     """train_gbdt driver end to end (single device)."""
     import subprocess, sys, os
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"))
     res = subprocess.run(
         [sys.executable, "-m", "repro.launch.train_gbdt", "--dataset", "higgs",
          "--rows", "2000", "--rounds", "5", "--max-bins", "32",
