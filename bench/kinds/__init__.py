@@ -1,0 +1,2 @@
+"""Traffic kinds, one module each, found by the `kind` a traffic file names
+(see bench/drivers.py)."""
