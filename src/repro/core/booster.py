@@ -59,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import compress as C
 from repro.core import metrics as M
 from repro.core import objectives as O
@@ -199,34 +200,35 @@ def _apply_stacked_trees(cfg: BoosterConfig, stacked: T.Tree, data,
     inside the fused update — differently depending on the data
     representation's producer graph, silently breaking the bit-identity
     between the in-memory and chunked paths (DESIGN.md §11)."""
-    k = stacked.feature.shape[0]
-    if getattr(data, "is_streamed", False):
-        # Streamed executor: the traversals run eagerly per chunk, but the
-        # scale-and-add must compile as ONE jitted program. XLA's CPU
-        # emitter contracts `margins + lr * delta` into a single-rounding
-        # FMA inside compiled programs — optimization_barrier does not
-        # block the instruction-level contraction — while eager op-by-op
-        # dispatch rounds the multiply and the add separately. Compiling
-        # the same mul/barrier/add subgraph standalone reproduces the
-        # scan body's rounding exactly (the bit-identity tests pin this).
-        mb = cfg.max_bins - 1
-        deltas = jnp.stack(
+    with jax.named_scope("margins"):
+        k = stacked.feature.shape[0]
+        if getattr(data, "is_streamed", False):
+            # Streamed executor: the traversals run eagerly per chunk, but the
+            # scale-and-add must compile as ONE jitted program. XLA's CPU
+            # emitter contracts `margins + lr * delta` into a single-rounding
+            # FMA inside compiled programs — optimization_barrier does not
+            # block the instruction-level contraction — while eager op-by-op
+            # dispatch rounds the multiply and the add separately. Compiling
+            # the same mul/barrier/add subgraph standalone reproduces the
+            # scan body's rounding exactly (the bit-identity tests pin this).
+            mb = cfg.max_bins - 1
+            deltas = jnp.stack(
+                [
+                    data.traverse_tree(jax.tree.map(lambda a: a[c], stacked),
+                                       mb, cfg.max_depth)
+                    for c in range(k)
+                ],
+                axis=1,
+            )
+            return _streamed_margin_update(margins, deltas, cfg.learning_rate)
+        updates = jnp.stack(
             [
-                data.traverse_tree(jax.tree.map(lambda a: a[c], stacked),
-                                   mb, cfg.max_depth)
+                _tree_margin_delta(cfg, jax.tree.map(lambda a: a[c], stacked), data)
                 for c in range(k)
             ],
             axis=1,
         )
-        return _streamed_margin_update(margins, deltas, cfg.learning_rate)
-    updates = jnp.stack(
-        [
-            _tree_margin_delta(cfg, jax.tree.map(lambda a: a[c], stacked), data)
-            for c in range(k)
-        ],
-        axis=1,
-    )
-    return margins + jax.lax.optimization_barrier(updates)
+        return margins + jax.lax.optimization_barrier(updates)
 
 
 @functools.partial(jax.jit, static_argnames=("lr",))
@@ -270,21 +272,23 @@ def _round_step_fn(cfg: BoosterConfig, obj: O.Objective, hist_builder=None):
                 "monotone or non-default seed use) — the round step needs "
                 "a per-round PRNG key (rkey)"
             )
-        gh_all = obj.grad(margins, y, **extra)  # (n, k, 2)
-        if fault is not None and round_idx is not None:
-            bad_round = int(fault.payload.get("round", 0))
-            bad_val = float(fault.payload.get("value", np.nan))
-            gh_all = jnp.where(jnp.equal(round_idx, bad_round),
-                               jnp.full_like(gh_all, bad_val), gh_all)
-        gh_raw = gh_all
-        if cfg.numeric_check == "clamp":
-            gh_all = RES.clamp_gradients(gh_all)
+        with jax.named_scope("gradient"):
+            gh_all = obj.grad(margins, y, **extra)  # (n, k, 2)
+            if fault is not None and round_idx is not None:
+                bad_round = int(fault.payload.get("round", 0))
+                bad_val = float(fault.payload.get("value", np.nan))
+                gh_all = jnp.where(jnp.equal(round_idx, bad_round),
+                                   jnp.full_like(gh_all, bad_val), gh_all)
+            gh_raw = gh_all
+            if cfg.numeric_check == "clamp":
+                gh_all = RES.clamp_gradients(gh_all)
         n_features = getattr(data, "n_features", None)
         if n_features is None:  # dense (n, f) bins array
             n_features = data.shape[1]
         trees = []
         for c in range(k):
-            gh_c = gh_all[:, c, :]
+            with jax.named_scope("gradient"):  # the class's (n, 2) view
+                gh_c = gh_all[:, c, :]
             ctx = None
             if stoch is not None:
                 ctx, gh_c = SMP.make_tree_context(
@@ -423,9 +427,12 @@ def _make_train_fn(cfg: BoosterConfig, obj: O.Objective, cuts: jax.Array,
             return body
 
         def _scan(body, margins0, eval_margins0, xs):
-            (margins, ev), (all_trees, tr_metrics, ev_metrics, flags) = \
-                jax.lax.scan(body, (margins0, tuple(eval_margins0)), xs,
-                             length=length if xs is None else None)
+            # `round` names what the round's phases leave unnamed: the loop
+            # itself, the ys-stack writes, the trees' stacking.
+            with jax.named_scope("round"):
+                (margins, ev), (all_trees, tr_metrics, ev_metrics, flags) = \
+                    jax.lax.scan(body, (margins0, tuple(eval_margins0)), xs,
+                                 length=length if xs is None else None)
             return margins, all_trees, tr_metrics, ev, ev_metrics, flags
 
         if stoch is not None:
@@ -674,27 +681,28 @@ class Booster:
         if dtrain.label is None:
             raise ValueError("dtrain must be constructed with label= to fit")
         self._metrics = self._resolve_metrics(eval_metric, custom_metric)
-        self.cuts = dtrain.cuts
-        self.base_score = float(self.obj.init_base_score(
-            dtrain.label, **O.config_kwargs(self.cfg)
-        ))
-        dmat = dtrain
-        while True:
-            try:
-                self._run_rounds(dmat, self.cfg.n_rounds, evals,
-                                 early_stopping_rounds, verbose_every,
-                                 callback, mesh, data_axes,
-                                 checkpoint_every=checkpoint_every,
-                                 checkpoint_path=checkpoint_path,
-                                 collective=collective,
-                                 compression=compression,
-                                 comm_tolerance=comm_tolerance)
-                return self
-            except Exception as exc:
-                if on_oom != "external" or not RES.is_oom(exc):
-                    raise
-                dmat = self._oom_fallback_matrix(dmat, exc)
-                reset()  # drop any partial history before the re-fit
+        with obs.span("fit"):
+            self.cuts = dtrain.cuts
+            self.base_score = float(self.obj.init_base_score(
+                dtrain.label, **O.config_kwargs(self.cfg)
+            ))
+            dmat = dtrain
+            while True:
+                try:
+                    self._run_rounds(dmat, self.cfg.n_rounds, evals,
+                                     early_stopping_rounds, verbose_every,
+                                     callback, mesh, data_axes,
+                                     checkpoint_every=checkpoint_every,
+                                     checkpoint_path=checkpoint_path,
+                                     collective=collective,
+                                     compression=compression,
+                                     comm_tolerance=comm_tolerance)
+                    return self
+                except Exception as exc:
+                    if on_oom != "external" or not RES.is_oom(exc):
+                        raise
+                    dmat = self._oom_fallback_matrix(dmat, exc)
+                    reset()  # drop any partial history before the re-fit
 
     def _oom_fallback_matrix(self, dmat, exc):
         """Next, smaller-footprint training matrix after a device OOM: an
@@ -760,12 +768,13 @@ class Booster:
         if eval_metric is not None or custom_metric is not None \
                 or self._metrics is None:
             self._metrics = self._resolve_metrics(eval_metric, custom_metric)
-        self._run_rounds(dtrain, n_rounds, evals, early_stopping_rounds,
-                         verbose_every, callback, mesh, data_axes,
-                         checkpoint_every=checkpoint_every,
-                         checkpoint_path=checkpoint_path,
-                         collective=collective, compression=compression,
-                         comm_tolerance=comm_tolerance)
+        with obs.call("update"):
+            self._run_rounds(dtrain, n_rounds, evals, early_stopping_rounds,
+                             verbose_every, callback, mesh, data_axes,
+                             checkpoint_every=checkpoint_every,
+                             checkpoint_path=checkpoint_path,
+                             collective=collective, compression=compression,
+                             comm_tolerance=comm_tolerance)
         return self
 
     @classmethod
@@ -1071,53 +1080,57 @@ class Booster:
             if ck:
                 nxt = min(nxt, (done // ck + 1) * ck)
             length = nxt - done
-            margins, all_trees, tr_metrics, eval_margins, ev_metrics, flags \
-                = run_chunk(length, rounds_before + done, margins,
-                            eval_margins)
-            self._handle_numeric_flags(flags, rounds_before + done)
-            # The scan's ys-stack IS the ensemble arena: (rounds, k, arena)
-            # fields reshaped to XGBoost's round-robin (rounds * k, arena)
-            # layout — no per-round host round trips.
-            chunk_ens = _scale_leaves(
-                _stack_to_ensemble(all_trees, k, self.base_score),
-                cfg.learning_rate,
-            )
-            run_ens = chunk_ens if run_ens is None \
-                else PR.concat_ensembles(run_ens, chunk_ens)
-            tr_host = [np.asarray(v) for v in tr_metrics]
-            ev_host = [[np.asarray(v) for v in vals] for vals in ev_metrics]
-            if record_every > 0:
-                self._record_history(done, length, tr_host, ev_host, metrics,
-                                     eval_names, rounds_before, record_every,
-                                     callback)
-            last_chunk = (done, tr_host, ev_host)
-            self._check_divergence(ev_host, eval_names, metrics,
-                                   rounds_before + done)
-            if es_on:
-                # The LAST metric of the LAST eval set drives stopping, in
-                # the direction that METRIC declares (XGBoost convention;
-                # the objective itself carries no direction). The stop
-                # check fires only at fit-relative multiples of e (and at
-                # the end), so extra checkpoint boundaries never change the
-                # stopping decision.
-                es_history.extend(ev_host[-1][-1].tolist())
-                if nxt % e == 0 or nxt == target:
-                    arr = np.asarray(es_history)
-                    best_round = int(np.argmax(arr) if metrics[-1].maximize
-                                     else np.argmin(arr))
-                    if (len(arr) - 1 - best_round) >= e:
-                        stopped = True
-            done = nxt
-            if ck and not stopped and done < target and done % ck == 0:
-                self._write_checkpoint(
-                    checkpoint_path, run_ens=run_ens, done=done,
-                    target=target, rounds_before=rounds_before,
-                    margins=margins, eval_margins=eval_margins,
-                    es_history=es_history, early_stopping_rounds=e,
-                    checkpoint_every=ck, verbose_every=verbose_every,
-                    eval_names=eval_names,
+            with obs.span("round.dispatch"):
+                margins, all_trees, tr_metrics, eval_margins, ev_metrics, \
+                    flags = run_chunk(length, rounds_before + done, margins,
+                                      eval_margins)
+            with obs.span("ensemble.append"):
+                # The scan's ys-stack IS the ensemble arena: (rounds, k,
+                # arena) fields reshaped to XGBoost's round-robin
+                # (rounds * k, arena) layout — no per-round host round trips.
+                chunk_ens = _scale_leaves(
+                    _stack_to_ensemble(all_trees, k, self.base_score),
+                    cfg.learning_rate,
                 )
-        jax.block_until_ready(margins)
+                run_ens = chunk_ens if run_ens is None \
+                    else PR.concat_ensembles(run_ens, chunk_ens)
+            with obs.span("round.host"):
+                self._handle_numeric_flags(flags, rounds_before + done)
+                tr_host = [np.asarray(v) for v in tr_metrics]
+                ev_host = [[np.asarray(v) for v in vals] for vals in ev_metrics]
+                if record_every > 0:
+                    self._record_history(done, length, tr_host, ev_host, metrics,
+                                         eval_names, rounds_before, record_every,
+                                         callback)
+                last_chunk = (done, tr_host, ev_host)
+                self._check_divergence(ev_host, eval_names, metrics,
+                                       rounds_before + done)
+                if es_on:
+                    # The LAST metric of the LAST eval set drives stopping, in
+                    # the direction that METRIC declares (XGBoost convention;
+                    # the objective itself carries no direction). The stop
+                    # check fires only at fit-relative multiples of e (and at
+                    # the end), so extra checkpoint boundaries never change the
+                    # stopping decision.
+                    es_history.extend(ev_host[-1][-1].tolist())
+                    if nxt % e == 0 or nxt == target:
+                        arr = np.asarray(es_history)
+                        best_round = int(np.argmax(arr) if metrics[-1].maximize
+                                         else np.argmin(arr))
+                        if (len(arr) - 1 - best_round) >= e:
+                            stopped = True
+                done = nxt
+                if ck and not stopped and done < target and done % ck == 0:
+                    self._write_checkpoint(
+                        checkpoint_path, run_ens=run_ens, done=done,
+                        target=target, rounds_before=rounds_before,
+                        margins=margins, eval_margins=eval_margins,
+                        es_history=es_history, early_stopping_rounds=e,
+                        checkpoint_every=ck, verbose_every=verbose_every,
+                        eval_names=eval_names,
+                    )
+        with obs.span("round.wait"):
+            jax.block_until_ready(margins)
         if self.comm_stats is not None:
             self.comm_stats["fallback_events"] = int(
                 run_chunk.fallback_events
@@ -1130,13 +1143,15 @@ class Booster:
             start, tr_host, ev_host = last_chunk
             final_r = done - 1
             if final_r % record_every != 0:
-                self._emit_record(final_r, final_r - start, tr_host, ev_host,
-                                  metrics, eval_names, rounds_before,
-                                  callback)
+                with obs.span("round.host"):
+                    self._emit_record(final_r, final_r - start, tr_host,
+                                      ev_host, metrics, eval_names,
+                                      rounds_before, callback)
 
         keep = best_round + 1 if stopped else done
-        full = run_ens if self.ensemble is None \
-            else PR.concat_ensembles(self.ensemble, run_ens)
+        with obs.span("ensemble.append"):
+            full = run_ens if self.ensemble is None \
+                else PR.concat_ensembles(self.ensemble, run_ens)
         if stopped and keep < done:
             # Early stopped: truncate the FULL ensemble to best_iteration+1
             # total rounds (best_round may precede a resume point, so the
@@ -1325,14 +1340,17 @@ class Booster:
                     f"{type(data).__name__} was quantised with different cuts "
                     "than this booster; build it with ref= the training matrix"
                 )
-            if isinstance(data, ExternalDMatrix):
-                return self._predict_margins_external(ens, data)
-            return ST.predict_margins_fused_packed(
-                ens, data.matrix.packed, data.bits, data.n_rows,
-                self.cfg.max_bins - 1, self.cfg.max_depth,
-            )
-        x = jnp.asarray(data, jnp.float32)
-        return ST.predict_margins_fused(ens, x, self.cfg.max_depth)
+            with obs.span("predict.traverse"):
+                if isinstance(data, ExternalDMatrix):
+                    return self._predict_margins_external(ens, data)
+                return ST.predict_margins_fused_packed(
+                    ens, data.matrix.packed, data.bits, data.n_rows,
+                    self.cfg.max_bins - 1, self.cfg.max_depth,
+                )
+        with obs.span("predict.put"):
+            x = jnp.asarray(data, jnp.float32)
+        with obs.span("predict.traverse"):
+            return ST.predict_margins_fused(ens, x, self.cfg.max_depth)
 
     def _predict_margins_external(self, ens, data: ExternalDMatrix):
         """Margins over an ExternalDMatrix by streaming packed chunks
@@ -1359,8 +1377,12 @@ class Booster:
         """Transformed predictions (probabilities / values / class ids) —
         the model knows its own objective, depth and class count.
         output_margin / iteration_range follow XGBoost's predict knobs."""
-        m = self.predict_margins(data, iteration_range=iteration_range)
-        return m if output_margin else self.obj.transform(m)
+        with obs.span("predict"):
+            m = self.predict_margins(data, iteration_range=iteration_range)
+            if output_margin:
+                return m
+            with obs.span("predict.transform"):
+                return self.obj.transform(m)
 
     def eval(self, dmat: DeviceDMatrix, name: str = "eval",
              metrics=None) -> dict:

@@ -209,11 +209,13 @@ def grow_tree(
     node_sum = jnp.zeros((na, 2), jnp.float32)
 
     positions = jnp.zeros(n, jnp.int32)  # all rows start at the root
-    root_sum = jnp.sum(gh, axis=0)
-    if collective is not None:
-        root_sum = collective.allreduce(root_sum)
-    elif axis_name is not None:
-        root_sum = jax.lax.psum(root_sum, (axis_name, *extra_axes))
+    with jax.named_scope("split"):
+        root_sum = jnp.sum(gh, axis=0)
+    with jax.named_scope("allreduce"):
+        if collective is not None:
+            root_sum = collective.allreduce(root_sum)
+        elif axis_name is not None:
+            root_sum = jax.lax.psum(root_sum, (axis_name, *extra_axes))
     node_sum = node_sum.at[0].set(root_sum)
     active = jnp.zeros(na, bool).at[0].set(True)
     # lossguide leaf budget: a tree starts as 1 leaf; each split adds 1.
@@ -233,177 +235,184 @@ def grow_tree(
     hist_prev = None
 
     for level in range(max_depth):
-        off = level_offset(level)
-        n_nodes = 2**level
+        with jax.named_scope(f"level{level}"):
+            off = level_offset(level)
+            n_nodes = 2**level
 
-        # --- BuildPartialHistograms (per-shard rows) ---------------------
-        local = jnp.where(
-            (positions >= off) & (positions < off + n_nodes),
-            positions - off,
-            n_nodes,
-        ).astype(jnp.int32)
-        if use_subtraction and level > 0:
-            hist = _histograms_by_subtraction(
-                bins, gh, local, hist_prev, n_nodes, max_bins,
-                hist_block_rows, row_ids=row_ids,
-            )
-        else:
-            hist = build(bins, gh, local, n_nodes, max_bins)
+            with jax.named_scope("histogram"):
+                # --- BuildPartialHistograms (per-shard rows) -------------
+                local = jnp.where(
+                    (positions >= off) & (positions < off + n_nodes),
+                    positions - off,
+                    n_nodes,
+                ).astype(jnp.int32)
+                if use_subtraction and level > 0:
+                    hist = _histograms_by_subtraction(
+                        bins, gh, local, hist_prev, n_nodes, max_bins,
+                        hist_block_rows, row_ids=row_ids,
+                    )
+                else:
+                    hist = build(bins, gh, local, n_nodes, max_bins)
             # --- AllReduceHistograms (paper: NCCL; here: psum, or a
-            # dist.Collective strategy with optional compressed payload) ---
-            if collective is not None:
-                hist = collective.allreduce_hist(hist)
-            elif axis_name is not None:
-                hist = jax.lax.psum(hist, (axis_name, *extra_axes))
-        hist_prev = hist
+            # dist.Collective strategy with optional compressed payload).
+            # The subtraction trick runs on one shard's rows only, so it
+            # never reaches a reduction.
+            with jax.named_scope("allreduce"):
+                if collective is not None:
+                    hist = collective.allreduce_hist(hist)
+                elif axis_name is not None:
+                    hist = jax.lax.psum(hist, (axis_name, *extra_axes))
+            hist_prev = hist
+            with jax.named_scope("split"):
+                # --- EvaluateSplit (prefix-sum scan over bins) -----------
+                parent = jax.lax.dynamic_slice_in_dim(node_sum, off, n_nodes)
+                feature_mask = (
+                    SMP.level_feature_mask(ctx, level, n_nodes, f)
+                    if ctx is not None else None
+                )
+                if mono_on:
+                    lvl_lo = jax.lax.dynamic_slice_in_dim(lower, off, n_nodes)
+                    lvl_hi = jax.lax.dynamic_slice_in_dim(upper, off, n_nodes)
+                    bounds = jnp.stack([lvl_lo, lvl_hi], axis=-1)
+                    sp = S.evaluate_splits(
+                        hist, parent, params, feature_mask=feature_mask,
+                        monotone=mono_arr, node_bounds=bounds,
+                    )
+                else:
+                    sp = S.evaluate_splits(hist, parent, params,
+                                           feature_mask=feature_mask)
+                if feature_axis is not None:
+                    sp = _combine_feature_shards(sp, f, feature_axis)
 
-        # --- EvaluateSplit (prefix-sum scan over bins) -------------------
-        parent = jax.lax.dynamic_slice_in_dim(node_sum, off, n_nodes)
-        feature_mask = (
-            SMP.level_feature_mask(ctx, level, n_nodes, f)
-            if ctx is not None else None
-        )
-        if mono_on:
-            lvl_lo = jax.lax.dynamic_slice_in_dim(lower, off, n_nodes)
-            lvl_hi = jax.lax.dynamic_slice_in_dim(upper, off, n_nodes)
-            bounds = jnp.stack([lvl_lo, lvl_hi], axis=-1)
-            sp = S.evaluate_splits(
-                hist, parent, params, feature_mask=feature_mask,
-                monotone=mono_arr, node_bounds=bounds,
-            )
-        else:
-            sp = S.evaluate_splits(hist, parent, params,
-                                   feature_mask=feature_mask)
-        if feature_axis is not None:
-            sp = _combine_feature_shards(sp, f, feature_axis)
+                lvl_active = jax.lax.dynamic_slice_in_dim(active, off, n_nodes)
+                will_split = lvl_active & (sp.gain > 0.0) & jnp.isfinite(sp.gain)
 
-        lvl_active = jax.lax.dynamic_slice_in_dim(active, off, n_nodes)
-        will_split = lvl_active & (sp.gain > 0.0) & jnp.isfinite(sp.gain)
+                if growth == "lossguide":
+                    # Keep only the top-`budget` gains among would-be splits.
+                    g = jnp.where(will_split, sp.gain, -jnp.inf)
+                    order = jnp.argsort(-g)  # descending
+                    rank = jnp.zeros(n_nodes, jnp.int32).at[order].set(
+                        jnp.arange(n_nodes, dtype=jnp.int32)
+                    )
+                    will_split = will_split & (rank < budget)
+                    budget = budget - jnp.sum(will_split)
 
-        if growth == "lossguide":
-            # Keep only the top-`budget` gains among would-be splits.
-            g = jnp.where(will_split, sp.gain, -jnp.inf)
-            order = jnp.argsort(-g)  # descending
-            rank = jnp.zeros(n_nodes, jnp.int32).at[order].set(
-                jnp.arange(n_nodes, dtype=jnp.int32)
-            )
-            will_split = will_split & (rank < budget)
-            budget = budget - jnp.sum(will_split)
+                idx = off + jnp.arange(n_nodes)
+                feature = feature.at[idx].set(jnp.where(will_split, sp.feature, 0))
+                split_bin = split_bin.at[idx].set(jnp.where(will_split, sp.split_bin, 0))
+                default_left = default_left.at[idx].set(will_split & sp.default_left)
+                gain_arr = gain_arr.at[idx].set(jnp.where(will_split, sp.gain, -jnp.inf))
+                is_leaf = is_leaf.at[idx].set(lvl_active & ~will_split)
+                lvl_leaf = S.leaf_value(parent, params.reg_lambda)
+                if mono_on:  # leaf weights respect the inherited bounds
+                    lvl_leaf = jnp.clip(lvl_leaf, lvl_lo, lvl_hi)
+                leaf_value = leaf_value.at[idx].set(
+                    jnp.where(lvl_active & ~will_split, lvl_leaf, 0.0)
+                )
 
+                # Children bookkeeping (sums come from the split evaluation — no
+                # extra pass over the data, mirroring the paper's histogram reuse).
+                lidx, ridx = 2 * idx + 1, 2 * idx + 2
+                node_sum = node_sum.at[lidx].set(jnp.where(will_split[:, None], sp.left_sum, 0.0))
+                node_sum = node_sum.at[ridx].set(jnp.where(will_split[:, None], sp.right_sum, 0.0))
+                active = active.at[lidx].set(will_split).at[ridx].set(will_split)
+
+                if mono_on:
+                    # Monotone bound propagation (XGBoost's scheme): the midpoint of
+                    # the clipped child weights becomes the dividing bound on the
+                    # constrained side; the other side inherits the parent's bound.
+                    wl = jnp.clip(S.leaf_value(sp.left_sum, params.reg_lambda),
+                                  lvl_lo, lvl_hi)
+                    wr = jnp.clip(S.leaf_value(sp.right_sum, params.reg_lambda),
+                                  lvl_lo, lvl_hi)
+                    mid = 0.5 * (wl + wr)
+                    csign = mono_arr[sp.feature]
+                    l_lo = jnp.where(csign < 0, mid, lvl_lo)
+                    l_hi = jnp.where(csign > 0, mid, lvl_hi)
+                    r_lo = jnp.where(csign > 0, mid, lvl_lo)
+                    r_hi = jnp.where(csign < 0, mid, lvl_hi)
+                    keep = ~will_split
+                    lower = lower.at[lidx].set(jnp.where(keep, -jnp.inf, l_lo))
+                    lower = lower.at[ridx].set(jnp.where(keep, -jnp.inf, r_lo))
+                    upper = upper.at[lidx].set(jnp.where(keep, jnp.inf, l_hi))
+                    upper = upper.at[ridx].set(jnp.where(keep, jnp.inf, r_hi))
+
+            with jax.named_scope("repartition"):
+                # --- RepartitionInstances --------------------------------
+                split_mask = jnp.zeros(na, bool).at[idx].set(will_split)
+                full_feature = jnp.zeros(na, jnp.int32).at[idx].set(feature[idx])
+                full_bin = jnp.zeros(na, jnp.int32).at[idx].set(split_bin[idx])
+                full_dl = jnp.zeros(na, bool).at[idx].set(default_left[idx])
+                if sampled and streamed_mode:
+                    positions = bins.update_positions_rows(
+                        positions, split_mask, full_feature, full_bin, full_dl,
+                        missing_bin, row_ids,
+                    )
+                elif streamed_mode:
+                    positions = bins.update_positions(
+                        positions, split_mask, full_feature, full_bin, full_dl,
+                        missing_bin,
+                    )
+                elif sampled and chunked_mode:
+                    positions = P.update_positions_chunked_rows(
+                        bins.packed, positions, split_mask, full_feature, full_bin,
+                        full_dl, missing_bin, bins.bits, bins.chunk_rows, row_ids,
+                    )
+                elif sampled:
+                    positions = P.update_positions_packed_rows(
+                        bins.packed, positions, split_mask, full_feature, full_bin,
+                        full_dl, missing_bin, bins.bits, row_ids,
+                    )
+                elif chunked_mode:
+                    positions = P.update_positions_chunked(
+                        bins.packed, positions, split_mask, full_feature, full_bin,
+                        full_dl, missing_bin, bins.bits, bins.chunk_rows, bins.n_rows,
+                    )
+                elif packed_mode:
+                    positions = P.update_positions_packed(
+                        bins.packed, positions, split_mask, full_feature, full_bin,
+                        full_dl, missing_bin, bins.bits,
+                    )
+                elif feature_axis is None:
+                    positions = P.update_positions(
+                        bins, positions, split_mask, full_feature, full_bin, full_dl,
+                        missing_bin,
+                    )
+                else:
+                    positions = _update_positions_feature_sharded(
+                        bins, positions, split_mask, full_feature, full_bin, full_dl,
+                        missing_bin, f, feature_axis,
+                    )
+
+    with jax.named_scope("split"):
+        # Final level: every still-active node is a leaf.
+        off = level_offset(max_depth)
+        n_nodes = 2**max_depth
         idx = off + jnp.arange(n_nodes)
-        feature = feature.at[idx].set(jnp.where(will_split, sp.feature, 0))
-        split_bin = split_bin.at[idx].set(jnp.where(will_split, sp.split_bin, 0))
-        default_left = default_left.at[idx].set(will_split & sp.default_left)
-        gain_arr = gain_arr.at[idx].set(jnp.where(will_split, sp.gain, -jnp.inf))
-        is_leaf = is_leaf.at[idx].set(lvl_active & ~will_split)
-        lvl_leaf = S.leaf_value(parent, params.reg_lambda)
-        if mono_on:  # leaf weights respect the inherited bounds
-            lvl_leaf = jnp.clip(lvl_leaf, lvl_lo, lvl_hi)
-        leaf_value = leaf_value.at[idx].set(
-            jnp.where(lvl_active & ~will_split, lvl_leaf, 0.0)
-        )
-
-        # Children bookkeeping (sums come from the split evaluation — no
-        # extra pass over the data, mirroring the paper's histogram reuse).
-        lidx, ridx = 2 * idx + 1, 2 * idx + 2
-        node_sum = node_sum.at[lidx].set(jnp.where(will_split[:, None], sp.left_sum, 0.0))
-        node_sum = node_sum.at[ridx].set(jnp.where(will_split[:, None], sp.right_sum, 0.0))
-        active = active.at[lidx].set(will_split).at[ridx].set(will_split)
-
+        lvl_active = jax.lax.dynamic_slice_in_dim(active, off, n_nodes)
+        parent = jax.lax.dynamic_slice_in_dim(node_sum, off, n_nodes)
+        is_leaf = is_leaf.at[idx].set(lvl_active)
+        final_leaf = S.leaf_value(parent, params.reg_lambda)
         if mono_on:
-            # Monotone bound propagation (XGBoost's scheme): the midpoint of
-            # the clipped child weights becomes the dividing bound on the
-            # constrained side; the other side inherits the parent's bound.
-            wl = jnp.clip(S.leaf_value(sp.left_sum, params.reg_lambda),
-                          lvl_lo, lvl_hi)
-            wr = jnp.clip(S.leaf_value(sp.right_sum, params.reg_lambda),
-                          lvl_lo, lvl_hi)
-            mid = 0.5 * (wl + wr)
-            csign = mono_arr[sp.feature]
-            l_lo = jnp.where(csign < 0, mid, lvl_lo)
-            l_hi = jnp.where(csign > 0, mid, lvl_hi)
-            r_lo = jnp.where(csign > 0, mid, lvl_lo)
-            r_hi = jnp.where(csign < 0, mid, lvl_hi)
-            keep = ~will_split
-            lower = lower.at[lidx].set(jnp.where(keep, -jnp.inf, l_lo))
-            lower = lower.at[ridx].set(jnp.where(keep, -jnp.inf, r_lo))
-            upper = upper.at[lidx].set(jnp.where(keep, jnp.inf, l_hi))
-            upper = upper.at[ridx].set(jnp.where(keep, jnp.inf, r_hi))
-
-        # --- RepartitionInstances ----------------------------------------
-        split_mask = jnp.zeros(na, bool).at[idx].set(will_split)
-        full_feature = jnp.zeros(na, jnp.int32).at[idx].set(feature[idx])
-        full_bin = jnp.zeros(na, jnp.int32).at[idx].set(split_bin[idx])
-        full_dl = jnp.zeros(na, bool).at[idx].set(default_left[idx])
-        if sampled and streamed_mode:
-            positions = bins.update_positions_rows(
-                positions, split_mask, full_feature, full_bin, full_dl,
-                missing_bin, row_ids,
+            final_leaf = jnp.clip(
+                final_leaf,
+                jax.lax.dynamic_slice_in_dim(lower, off, n_nodes),
+                jax.lax.dynamic_slice_in_dim(upper, off, n_nodes),
             )
-        elif streamed_mode:
-            positions = bins.update_positions(
-                positions, split_mask, full_feature, full_bin, full_dl,
-                missing_bin,
-            )
-        elif sampled and chunked_mode:
-            positions = P.update_positions_chunked_rows(
-                bins.packed, positions, split_mask, full_feature, full_bin,
-                full_dl, missing_bin, bins.bits, bins.chunk_rows, row_ids,
-            )
-        elif sampled:
-            positions = P.update_positions_packed_rows(
-                bins.packed, positions, split_mask, full_feature, full_bin,
-                full_dl, missing_bin, bins.bits, row_ids,
-            )
-        elif chunked_mode:
-            positions = P.update_positions_chunked(
-                bins.packed, positions, split_mask, full_feature, full_bin,
-                full_dl, missing_bin, bins.bits, bins.chunk_rows, bins.n_rows,
-            )
-        elif packed_mode:
-            positions = P.update_positions_packed(
-                bins.packed, positions, split_mask, full_feature, full_bin,
-                full_dl, missing_bin, bins.bits,
-            )
-        elif feature_axis is None:
-            positions = P.update_positions(
-                bins, positions, split_mask, full_feature, full_bin, full_dl,
-                missing_bin,
-            )
-        else:
-            positions = _update_positions_feature_sharded(
-                bins, positions, split_mask, full_feature, full_bin, full_dl,
-                missing_bin, f, feature_axis,
-            )
-
-    # Final level: every still-active node is a leaf.
-    off = level_offset(max_depth)
-    n_nodes = 2**max_depth
-    idx = off + jnp.arange(n_nodes)
-    lvl_active = jax.lax.dynamic_slice_in_dim(active, off, n_nodes)
-    parent = jax.lax.dynamic_slice_in_dim(node_sum, off, n_nodes)
-    is_leaf = is_leaf.at[idx].set(lvl_active)
-    final_leaf = S.leaf_value(parent, params.reg_lambda)
-    if mono_on:
-        final_leaf = jnp.clip(
-            final_leaf,
-            jax.lax.dynamic_slice_in_dim(lower, off, n_nodes),
-            jax.lax.dynamic_slice_in_dim(upper, off, n_nodes),
+        leaf_value = leaf_value.at[idx].set(
+            jnp.where(lvl_active, final_leaf, 0.0)
         )
-    leaf_value = leaf_value.at[idx].set(
-        jnp.where(lvl_active, final_leaf, 0.0)
-    )
 
-    # Raw-space thresholds for prediction on unquantised inputs.
-    if feature_axis is None:
-        threshold = cuts[feature, jnp.clip(split_bin, 0, cuts.shape[1] - 1)]
-    else:
-        my = jax.lax.axis_index(feature_axis)
-        f_loc = jnp.clip(feature - my * f, 0, f - 1)
-        owned = (feature // f) == my
-        thr_local = cuts[f_loc, jnp.clip(split_bin, 0, cuts.shape[1] - 1)]
-        threshold = jax.lax.psum(jnp.where(owned, thr_local, 0.0), feature_axis)
-    threshold = jnp.where(is_leaf, jnp.inf, threshold)
+        # Raw-space thresholds for prediction on unquantised inputs.
+        if feature_axis is None:
+            threshold = cuts[feature, jnp.clip(split_bin, 0, cuts.shape[1] - 1)]
+        else:
+            my = jax.lax.axis_index(feature_axis)
+            f_loc = jnp.clip(feature - my * f, 0, f - 1)
+            owned = (feature // f) == my
+            thr_local = cuts[f_loc, jnp.clip(split_bin, 0, cuts.shape[1] - 1)]
+            threshold = jax.lax.psum(jnp.where(owned, thr_local, 0.0), feature_axis)
+        threshold = jnp.where(is_leaf, jnp.inf, threshold)
 
     return Tree(
         feature=feature,

@@ -160,13 +160,15 @@ def make_distributed_round(
             rep.n_features if cfg.compress_matrix or chunked
             else rep.shape[1]
         )
-        gh_all = obj.grad(margins, y, **cfg_kw)
-        gh_raw = gh_all
-        if cfg.numeric_check == "clamp":
-            gh_all = RES.clamp_gradients(gh_all)
+        with jax.named_scope("gradient"):
+            gh_all = obj.grad(margins, y, **cfg_kw)
+            gh_raw = gh_all
+            if cfg.numeric_check == "clamp":
+                gh_all = RES.clamp_gradients(gh_all)
         trees = []
         for c in range(k):
-            gh_c = gh_all[:, c, :]
+            with jax.named_scope("gradient"):  # the class's (n, 2) view
+                gh_c = gh_all[:, c, :]
             ctx = None
             if stoch is not None:
                 n_local = margins.shape[0]

@@ -50,6 +50,7 @@ def decompress(
         out_shape=jax.ShapeDtypeStruct(
             (n_fblk * f_blk, n_wblk * w_blk * spw), jnp.int32
         ),
+        name="decompress",
         interpret=interpret,
     )(packed_p)
     return out[:f, :n_rows].T

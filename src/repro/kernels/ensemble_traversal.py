@@ -189,6 +189,7 @@ def ensemble_margins_kernel(
         out_shape=jax.ShapeDtypeStruct(
             (n_rblk * rows_blk, n_classes), jnp.float32
         ),
+        name="ensemble_traversal",
         interpret=interpret,
     )(
         feature_p, threshold_p, default_p, is_leaf_p, leaf_val_p,

@@ -142,6 +142,7 @@ def histogram_packed(
         out_shape=jax.ShapeDtypeStruct(
             (n_nblk * nodes_blk, n_fblk * f_blk, max_bins, 2), jnp.float32
         ),
+        name="histogram_packed",
         interpret=interpret,
     )(packed_p, gh_p, pos_p)
     return out[:n_nodes, :f]
@@ -397,6 +398,7 @@ def build_histograms_packed_kernel(
             pltpu.VMEM((buffer_depth, 1, rows_blk), jnp.int32),
             pltpu.SemaphoreType.DMA((3, buffer_depth)),
         ],
+        name="histogram_private",
         interpret=interpret,
     )(packed_p, gh_p, pos_p)
     merged = _tree_add(partials)  # (F_pad, 2*NODES_PAD, B)

@@ -109,6 +109,7 @@ def quantile_cuts_from_sorted(
         ],
         out_specs=pl.BlockSpec((f_blk, n_cuts), lambda fb: (fb, 0)),
         out_shape=jax.ShapeDtypeStruct((n_fblk * f_blk, n_cuts), jnp.float32),
+        name="quantile_cuts",
         interpret=interpret,
     )(srt_p, nv_p)
     # Final ascending re-sort (pushes +inf dedup markers to the tail), same

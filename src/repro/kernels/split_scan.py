@@ -87,6 +87,7 @@ def split_scan(
         ],
         out_specs=pl.BlockSpec((1, f_blk, 4), lambda n, fb: (n, fb, 0)),
         out_shape=jax.ShapeDtypeStruct((n_nodes, n_fblk * f_blk, 4), jnp.float32),
+        name="split_scan",
         interpret=interpret,
     )(hist_p, parent_sum)
     return out[:, :f]

@@ -16,9 +16,10 @@ request traffic rather than training:
     NaN, and the device transfer always leaves from the same page-aligned
     allocation (the pinned-host pattern; on CPU it simply avoids per-call
     allocation).
-  * Latency accounting. Every call records rows, wall seconds, and whether
-    it compiled; `stats()` reduces to p50/p99 latency and rows/s with
-    compile calls excluded (they are warmup, not steady state).
+  * Latency accounting. Every call records rows, wall seconds, and the
+    programs it compiled, in a bounded store (`repro.obs`); `stats()` reduces the
+    stored calls to p50/p99 latency and rows per summed latency second,
+    with compile calls excluded (they are warmup, not steady state).
 
 Validation mirrors DeviceDMatrix: inputs must be 2-D with the model's
 feature count, ±inf is rejected with the same remedy message, NaN stays
@@ -26,12 +27,11 @@ legal missing.
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import predict as PR
 
 DEFAULT_BUCKETS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)
@@ -93,7 +93,7 @@ class PredictEngine:
         self._compiled: dict[int, object] = {}  # bucket -> jit'd fn
         self._staging: dict[int, np.ndarray] = {}
         self._trace_count = 0  # bumped at trace time; tests assert on it
-        self.calls: list[dict] = []
+        self.calls = obs.store()  # the last obs.MAXLEN calls' records
 
     # --- compiled cache ----------------------------------------------------
     @property
@@ -150,51 +150,49 @@ class PredictEngine:
     def predict(self, x) -> np.ndarray:
         """Serve one request batch. Accepts any 2-D array-like; rows beyond
         the largest bucket are processed in largest-bucket slices."""
-        t0 = time.perf_counter()
-        x = np.asarray(x)
-        if x.ndim != 2:
-            raise ValueError(
-                f"x must be 2-D (n_rows, n_features), got shape {x.shape}"
-            )
-        if x.shape[1] != self.n_features:
-            raise ValueError(
-                f"x has {x.shape[1]} features, model expects "
-                f"{self.n_features}"
-            )
-        if x.shape[0] == 0:
-            raise ValueError("x has 0 rows; nothing to predict")
-        if np.isinf(x).any():
-            raise ValueError(
-                "x contains infinite feature values; replace ±inf with NaN "
-                "(the legal missing marker) or a large finite value before "
-                "prediction"
-            )
+        with obs.call("engine.predict", into=self.calls) as rec:
+            x = np.asarray(x)
+            if x.ndim != 2:
+                raise ValueError(
+                    f"x must be 2-D (n_rows, n_features), got shape {x.shape}"
+                )
+            if x.shape[1] != self.n_features:
+                raise ValueError(
+                    f"x has {x.shape[1]} features, model expects "
+                    f"{self.n_features}"
+                )
+            if x.shape[0] == 0:
+                raise ValueError("x has 0 rows; nothing to predict")
+            if np.isinf(x).any():
+                raise ValueError(
+                    "x contains infinite feature values; replace ±inf with "
+                    "NaN (the legal missing marker) or a large finite value "
+                    "before prediction"
+                )
 
-        top = self._buckets[-1]
-        parts = []
-        compiled_before = self._trace_count
-        for s in range(0, x.shape[0], top):
-            part = x[s : s + top]
-            bucket = self._bucket_for(part.shape[0])
-            fn = self._compiled_for(bucket)
-            block = jnp.asarray(self._stage(part, bucket))
-            out = fn(self._ens, block)
-            parts.append(np.asarray(out)[: part.shape[0]])
-        result = parts[0] if len(parts) == 1 else np.concatenate(parts)
-
-        self.calls.append({
-            "rows": int(x.shape[0]),
-            "seconds": time.perf_counter() - t0,
-            "compiled": self._trace_count > compiled_before,
-        })
+            top = self._buckets[-1]
+            parts = []
+            for s in range(0, x.shape[0], top):
+                part = x[s : s + top]
+                bucket = self._bucket_for(part.shape[0])
+                fn = self._compiled_for(bucket)
+                block = jnp.asarray(self._stage(part, bucket))
+                out = fn(self._ens, block)
+                parts.append(np.asarray(out)[: part.shape[0]])
+            result = parts[0] if len(parts) == 1 else np.concatenate(parts)
+            rec["rows"] = int(x.shape[0])
         return result
 
     # --- accounting --------------------------------------------------------
     def stats(self, include_warmup: bool = False) -> dict:
-        """p50/p99 latency and throughput over recorded calls. Calls that
-        paid an XLA trace are excluded unless include_warmup=True."""
+        """p50/p99 latency and throughput over the recorded calls (the last
+        `obs.MAXLEN`). Calls that obtained a program (the record's
+        `compiles`: compiled, or loaded from the persistent cache) are
+        excluded unless include_warmup=True. `rows_per_s` is rows over the
+        summed latency of those calls, not over wall time: idle time between
+        requests does not count, and overlapping calls would count twice."""
         calls = [
-            c for c in self.calls if include_warmup or not c["compiled"]
+            c for c in self.calls if include_warmup or not obs.compiles(c)
         ]
         if not calls:
             return {"n_calls": 0}

@@ -98,15 +98,21 @@ def _blocked_leaves(table, leaf_value, lookup, n_rows: int, max_depth: int):
         t5, lv = blk
 
         def body(__, node):
-            g = t5[tree_ix, node]  # (tb, N, 5): the level's one table gather
-            f = g[..., 0].astype(jnp.int32)
-            v, is_missing = lookup(f)
-            go_left = jnp.where(is_missing, g[..., 2] > 0.5, v <= g[..., 1])
-            return jnp.where(go_left, g[..., 3], g[..., 4]).astype(jnp.int32)
+            with jax.named_scope("route"):
+                g = t5[tree_ix, node]  # (tb, N, 5): the level's table gather
+                f = g[..., 0].astype(jnp.int32)
+            with jax.named_scope("lookup"):
+                v, is_missing = lookup(f)
+            with jax.named_scope("route"):
+                go_left = jnp.where(is_missing, g[..., 2] > 0.5,
+                                    v <= g[..., 1])
+                return jnp.where(go_left, g[..., 3],
+                                 g[..., 4]).astype(jnp.int32)
 
         node = jnp.zeros((tb, n_rows), jnp.int32)
         node = jax.lax.fori_loop(0, max_depth, body, node)
-        return None, lv[tree_ix, node]
+        with jax.named_scope("leaf"):
+            return None, lv[tree_ix, node]
 
     _, leaves = jax.lax.scan(one_block, None, (tables, leaf_values))
     return leaves.reshape(-1, n_rows)[:n_trees]  # (T, N)
@@ -124,8 +130,9 @@ def traverse_ensemble_raw(
         v = x[row_ix, f]  # (tb, N) gather on the row block
         return v, jnp.isnan(v)
 
-    table = _stacked_table(feature, threshold, default_left, is_leaf)
-    return _blocked_leaves(table, leaf_value, lookup, n_rows, max_depth)
+    with jax.named_scope("traverse"):
+        table = _stacked_table(feature, threshold, default_left, is_leaf)
+        return _blocked_leaves(table, leaf_value, lookup, n_rows, max_depth)
 
 
 def traverse_ensemble_packed(
@@ -141,17 +148,24 @@ def traverse_ensemble_packed(
     from repro.core import compress as C
 
     spw = C.symbols_per_word(bits)
-    row = jnp.arange(n_rows, dtype=jnp.int32)
-    word_ix = (row // spw)[None, :]  # (1, N)
-    shift = ((row % spw).astype(jnp.uint32) * jnp.uint32(bits))[None, :]
     mask = jnp.uint32((1 << bits) - 1)
 
     def lookup(f):
         b = (packed[f, word_ix] >> shift) & mask
         return b.astype(jnp.float32), b == jnp.uint32(missing_bin)
 
-    table = _stacked_table(feature, split_bin, default_left, is_leaf)
-    return _blocked_leaves(table, leaf_value, lookup, n_rows, max_depth)
+    with jax.named_scope("traverse"):
+        row = jnp.arange(n_rows, dtype=jnp.int32)
+        word_ix = (row // spw)[None, :]  # (1, N)
+        shift = ((row % spw).astype(jnp.uint32) * jnp.uint32(bits))[None, :]
+        table = _stacked_table(feature, split_bin, default_left, is_leaf)
+        return _blocked_leaves(table, leaf_value, lookup, n_rows, max_depth)
+
+
+def _fold(leaves: jax.Array, ens: PR.Ensemble, n_rows: int) -> jax.Array:
+    """`core.predict._fold_classes`, named `traverse/fold` in traces."""
+    with jax.named_scope("traverse"), jax.named_scope("fold"):
+        return PR._fold_classes(leaves, ens, n_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("max_depth",))
@@ -167,7 +181,7 @@ def predict_margins_fused(
         ens.feature, ens.threshold, ens.default_left, ens.leaf_value,
         ens.is_leaf, x, max_depth,
     )
-    return PR._fold_classes(leaves, ens, x.shape[0])
+    return _fold(leaves, ens, x.shape[0])
 
 
 @functools.partial(
@@ -183,7 +197,7 @@ def predict_margins_fused_packed(
         ens.feature, ens.split_bin, ens.default_left, ens.leaf_value,
         ens.is_leaf, packed, bits, n_rows, missing_bin, max_depth,
     )
-    return PR._fold_classes(leaves, ens, n_rows)
+    return _fold(leaves, ens, n_rows)
 
 
 @functools.partial(
@@ -230,4 +244,4 @@ def predict_margins_fused_chunked(
     leaves = jnp.moveaxis(leaves, 0, 1).reshape(
         leaves.shape[1], -1
     )[:, :n_rows]  # (T, N) in global row order
-    return PR._fold_classes(leaves, ens, n_rows)
+    return _fold(leaves, ens, n_rows)
