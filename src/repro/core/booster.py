@@ -1319,10 +1319,10 @@ class Booster:
         DeviceDMatrix (bin-space traversal on the packed words — exact, since
         thresholds are cut values and quantisation is searchsorted-left).
 
-        Batch inference runs the fused ensemble traversal (all trees x all
-        rows per level; serve/traversal.py) — bit-identical to the per-tree
-        scan the training loop uses, in max_depth launches instead of
-        n_trees scan steps.
+        Batch inference runs the fused ensemble traversal (blocks of trees
+        evaluated densely over all rows, no per-row gather;
+        serve/traversal.py) — bit-identical to the per-tree scan the
+        training loop uses.
 
         iteration_range=(a, b) restricts to boosting rounds [a, b), XGBoost
         semantics (b=0 means "through the last round"); the default is the

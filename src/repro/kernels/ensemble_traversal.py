@@ -1,11 +1,12 @@
 """Pallas TPU kernel: fused ensemble traversal for batch inference.
 
-The serving path (`repro.serve.traversal`) advances ALL trees x a row block
-one level per step. Its XLA form routes each level through arbitrary
-gathers (arena SoA lookup per (tree, node), input lookup per (row,
-feature)); TPUs have no fast arbitrary gather, so — exactly as the
-histogram kernel recasts atomicAdd scatter (DESIGN.md §4) — this kernel
-recasts both gathers as dense **one-hot matmuls on the MXU**:
+The serving path (`repro.serve.traversal`) evaluates every split node of a
+tree block densely over the rows and picks each leaf by a path one-hot,
+in XLA elementwise operations with no per-row gather. This kernel walks
+the trees level by level instead and, exactly as the histogram kernel
+recasts atomicAdd scatter (DESIGN.md §4), recasts the walk's two per-row
+lookups (the arena record of the row's node, the row's feature value) as
+dense **one-hot matmuls on the MXU**:
 
     node one-hot  (TB, RB, A) @ arena field (TB, A)  -> per-pair select
     feat one-hot  (TB, RB, F) @ row block   (RB, F)  -> per-pair value

@@ -3,10 +3,10 @@
 The training side of the paper got six PRs; this package is the serving
 side: a dedicated batched-inference stack over the compact ensemble arena.
 
-  * `traversal`  — fused ensemble traversal: ALL trees x a row block advance
-    one level per step in a single program (levelwise gathers on the arena's
-    SoA arrays), replacing the per-tree `lax.scan` of `core.predict` for
-    batch inference. Bin-space fast path when the model carries cut points,
+  * `traversal`  — fused ensemble traversal: blocks of trees evaluate every
+    split node densely over a row block and pick each row's leaf by a path
+    one-hot, with no per-row gather, replacing the per-tree `lax.scan` of
+    `core.predict` for batch inference. Bin-space fast path when the model carries cut points,
     raw-threshold path otherwise; a Pallas kernel lives in
     `kernels.ensemble_traversal` with the XLA form as its parity oracle.
   * `engine`     — `PredictEngine`: shape-bucketed compiled predict caches

@@ -10,6 +10,9 @@ bumps at trace time only). The Pallas kernel is validated in interpret
 mode against the XLA oracle (matmul accumulation differs, so to
 tolerance).
 """
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -112,6 +115,141 @@ def test_booster_predict_routes_through_fused(binary):
             ens, pb.packed, pb.bits, d.n_rows, d.max_bins - 1, md
         )),
     )
+
+
+# --- dense form: ragged arenas, exact ties, NaN rows, block sizes ----------
+
+_GRID = np.linspace(-1.5, 1.5, 7).astype(np.float32)
+
+
+def _ragged_ensemble(rng, n_trees, depth, n_features, n_bins):
+    """Random trees whose leaves sit at any depth up to `depth`. Slots below
+    a leaf are inactive and, like the leaves, hold out-of-range feature ids
+    and junk thresholds and values; thresholds come from a small grid (and
+    bin ids from a small range) so rows can sit exactly on them."""
+    a = 2 ** (depth + 1) - 1
+    active = np.zeros((n_trees, a), bool)
+    is_leaf = np.zeros((n_trees, a), bool)
+    active[:, 0] = True
+    for s in range(a):
+        d = (s + 1).bit_length() - 1
+        stop = (d == depth) | (rng.random(n_trees) < (0.25 if d else 0.1))
+        is_leaf[:, s] = active[:, s] & stop
+        if d < depth:
+            grow = active[:, s] & ~is_leaf[:, s]
+            active[:, 2 * s + 1] = active[:, 2 * s + 2] = grow
+    split = active & ~is_leaf
+    feature = rng.integers(0, n_features, (n_trees, a))
+    feature[~split] = rng.choice([-3, -1, n_features, n_features + 7],
+                                 (~split).sum())
+    threshold = rng.choice(_GRID, (n_trees, a))
+    threshold[~active] = np.nan
+    leaf_value = rng.normal(size=(n_trees, a)).astype(np.float32)
+    leaf_value[~active] = 1e30
+    leaf_value[is_leaf & (rng.random((n_trees, a)) < 0.05)] = -0.0
+    return PR.Ensemble(
+        feature=jnp.asarray(feature, jnp.int32),
+        split_bin=jnp.asarray(rng.integers(0, n_bins - 1, (n_trees, a)),
+                              jnp.int32),
+        threshold=jnp.asarray(threshold, jnp.float32),
+        default_left=jnp.asarray(rng.random((n_trees, a)) < 0.5),
+        leaf_value=jnp.asarray(leaf_value),
+        is_leaf=jnp.asarray(is_leaf),
+        gain=jnp.zeros((n_trees, a), jnp.float32),
+    )
+
+
+def _dense_case(depth, n_rows=1000, n_trees=13, n_features=6, n_bins=16):
+    rng = np.random.default_rng(depth)
+    ens = _ragged_ensemble(rng, n_trees, depth, n_features, n_bins)
+    x = rng.normal(size=(n_rows, n_features)).astype(np.float32)
+    on_grid = rng.random(x.shape) < 0.4
+    x[on_grid] = rng.choice(_GRID, on_grid.sum())  # ties: v == threshold
+    x[rng.random(x.shape) < 0.1] = np.nan
+    x[:5] = np.nan  # rows missing everywhere: default directions alone
+    bins = rng.integers(0, n_bins - 1, x.shape)
+    bins[np.isnan(x)] = n_bins - 1  # the missing bin
+    return ens, x, bins
+
+
+@pytest.mark.parametrize("plane_bytes", [None, 1 << 20],
+                         ids=["real_blocks", "small_blocks"])
+@pytest.mark.parametrize("mode", ["raw", "packed"])
+@pytest.mark.parametrize("depth", [3, 6, 8, 10])
+def test_dense_form_bit_identical(depth, mode, plane_bytes, monkeypatch):
+    """The dense form against core.predict's per-tree walk, exactly. With a
+    small plane budget the 13 trees and 1,000 rows split into ragged tree
+    and row blocks; with the real one, into what the shapes give."""
+    from repro.core import compress as C
+
+    ens, x, bins = _dense_case(depth)
+    n_rows, n_bins, bits = x.shape[0], 16, 5  # 6 rows a word: padded words
+    if plane_bytes is not None:
+        monkeypatch.setattr(TV, "_PLANE_BYTES", plane_bytes)
+        tb, rb = TV._block_sizes(ens.n_trees, n_rows, depth)
+        assert ens.n_trees % tb or depth == 3
+        assert (rb < n_rows and n_rows % rb) or depth == 3
+    if mode == "raw":
+        ref = PR.predict_raw(ens, jnp.asarray(x), depth)
+        got = jax.jit(lambda e, a: TV._fold(TV.traverse_ensemble_raw(
+            e.feature, e.threshold, e.default_left, e.leaf_value,
+            e.is_leaf, a, depth), e, n_rows))(ens, jnp.asarray(x))
+    else:
+        packed = C.pack(jnp.asarray(bins), bits)
+        ref = PR.predict_binned_packed(ens, packed, bits, n_rows,
+                                       n_bins - 1, depth)
+        got = jax.jit(lambda e, p: TV._fold(TV.traverse_ensemble_packed(
+            e.feature, e.split_bin, e.default_left, e.leaf_value,
+            e.is_leaf, p, bits, n_rows, n_bins - 1, depth), e, n_rows))(
+                ens, packed)
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+
+
+def test_block_sizes_follow_the_shapes():
+    """Rows split into even lane multiples only where 8 trees over every
+    row would pass the budget (275,000 rows at depth 6: 34 blocks of 8
+    trees by 8,192 rows); small batches take half the trees a block, so
+    the blocks stay a loop."""
+    assert TV._block_sizes(500, 275_000, 6) == (8, 8_192)
+    assert TV._block_sizes(500, 275_000, 10) == (8, 512)
+    assert TV._block_sizes(500, 4_096, 6) == (16, 4_096)
+    assert TV._block_sizes(500, 16, 6) == (250, 16)
+    assert TV._block_sizes(3, 275_000, 3) == (2, 137_600)
+    assert TV._block_sizes(1, 100, 6) == (1, 100)
+    for t, n, d in [(500, 275_000, 6), (500, 2_200_000, 8), (7, 999, 10)]:
+        tb, rb = TV._block_sizes(t, n, d)
+        assert 2**d * tb * rb * 4 <= TV._PLANE_BYTES
+        assert rb == n or rb % 128 == 0
+
+
+_GATHER = re.compile(r'"stablehlo\.(?:dynamic_)?gather"\(.*?\) .*?: '
+                     r'\(tensor<[^>]*>, tensor<([^>]*)>')
+
+
+def _gather_index_dims(lowered) -> list[list[int]]:
+    return [[int(v) for v in shape.split("x")[:-1]]
+            for shape in _GATHER.findall(lowered.as_text())]
+
+
+@pytest.mark.parametrize("n_rows", [1000, 1500])
+def test_no_gather_is_indexed_per_row(binary, n_rows):
+    """Neither fused traversal gathers by a per-row index: no gather's index
+    operand has a dimension of the row count."""
+    from repro.core import compress as C
+
+    bst, d, _, _ = binary
+    ens, md = bst.ensemble, bst.ensemble.max_depth
+    x = jax.ShapeDtypeStruct((n_rows, 7), jnp.float32)
+    words = jax.ShapeDtypeStruct(
+        (7, -(-n_rows // C.symbols_per_word(d.bits))), jnp.uint32)
+    for lowered in (
+        TV.predict_margins_fused.lower(ens, x, md),
+        TV.predict_margins_fused_packed.lower(
+            ens, words, d.bits, n_rows, d.max_bins - 1, md),
+    ):
+        dims = _gather_index_dims(lowered)
+        assert dims, "no gather found: the pattern no longer reads the HLO"
+        assert all(n_rows not in ds for ds in dims), dims
 
 
 # --- Pallas kernel (interpret mode) -----------------------------------------

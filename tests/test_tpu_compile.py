@@ -16,8 +16,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import predict as PR
 from repro.core import quantile as Q
 from repro.kernels.histogram import build_histograms_packed_kernel
+from repro.serve import traversal as ST
 
 N_FEATURES, MAX_BINS, BITS = 28, 256, 8
 
@@ -70,3 +72,21 @@ def test_histogram_kernel_compiles(one_chip, n_nodes):
         packed, gh, pos, n_nodes, MAX_BINS, BITS, interpret=False))
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n_rows", [275_000, 2_200_000])
+def test_fused_traversal_compiles_at_the_scoring_size(one_chip, n_rows):
+    """The dense fused traversal of 500 depth-6 trees over raw f32 rows at
+    the Higgs width: one `higgs.score` call (275,000 rows) and the whole
+    2.2M-row holdout each fit one chip (the compiler refuses a program over
+    its memory)."""
+    n_trees, depth = 500, 6
+    arena = 2 ** (depth + 1) - 1
+    field = {k: _spec((n_trees, arena), dt, one_chip) for k, dt in (
+        ("feature", jnp.int32), ("split_bin", jnp.int32),
+        ("threshold", jnp.float32), ("default_left", jnp.bool_),
+        ("leaf_value", jnp.float32), ("is_leaf", jnp.bool_),
+        ("gain", jnp.float32))}
+    ens = PR.Ensemble(**field)
+    x = _spec((n_rows, N_FEATURES), jnp.float32, one_chip)
+    ST.predict_margins_fused.lower(ens, x, depth).compile()
